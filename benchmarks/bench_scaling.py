@@ -4,17 +4,18 @@ Fixed iteration count (tol=0, max_iter fixed) isolates per-iteration cost;
 the log-log slope of time vs n should be ~1 for RF and ~2 for Sin.
 
 ``--mesh`` adds the distributed axis: per-iteration time of the sharded
-solver (scaling AND log mode) vs device count on CPU virtual devices
-(meshes over subsets of the 8 forced host devices), plus the derived
-per-iteration collective overhead vs the 1-device run — the measured twin
-of the EXPERIMENTS.md §Roofline psum-cost estimate. If the process was
-started with a single device it re-execs itself with
-``XLA_FLAGS=--xla_force_host_platform_device_count=8``.
+solver (scaling AND log mode) vs device count, over meshes of the first
+1, 2, 4, ... ``--devices`` devices, plus the derived per-iteration
+collective overhead vs the 1-device run — the measured twin of the
+EXPERIMENTS.md §Roofline psum-cost estimate. Before JAX starts its
+backend the script asks the CPU platform for ``--devices`` virtual
+devices (``--xla_force_host_platform_device_count``, which only the CPU
+backend reads); on an accelerator with fewer devices than asked for it
+exits with an error instead of measuring anything else.
 """
 from __future__ import annotations
 
 import os
-import subprocess
 import sys
 import time
 
@@ -125,32 +126,29 @@ def main_mesh(n: int = 4096, r: int = 256, eps: float = 0.5,
     return rows
 
 
-def _reexec_with_devices(count: int = 8):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + f" --xla_force_host_platform_device_count={count}"
-                        ).strip()
-    # host-device forcing only multiplies the CPU backend — pin it, or a
-    # single-GPU machine would still see 1 device and re-exec forever
-    env["JAX_PLATFORMS"] = "cpu"
-    env["_REPRO_MESH_BENCH_CHILD"] = "1"        # belt-and-braces recursion stop
-    res = subprocess.run([sys.executable, "-m", "benchmarks.bench_scaling",
-                          "--mesh"], env=env)
-    sys.exit(res.returncode)
-
-
 if __name__ == "__main__":
     import argparse
 
     ap = argparse.ArgumentParser()
     ap.add_argument("--mesh", action="store_true",
-                    help="measure sharded iteration time vs device count "
-                         "(forces 8 virtual CPU devices if needed)")
+                    help="measure sharded iteration time vs device count")
+    ap.add_argument("--devices", type=int, default=8,
+                    help="largest mesh (default 8); on CPU this many "
+                         "virtual devices are created")
     args = ap.parse_args()
     if args.mesh:
-        if (len(jax.devices()) < 2
-                and not os.environ.get("_REPRO_MESH_BENCH_CHILD")):
-            _reexec_with_devices(8)
-        main_mesh()
+        # read when the backend starts, i.e. at the first jax.devices()
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.devices}"
+        ).strip()
+        devices = jax.devices()
+        if len(devices) < args.devices:
+            sys.exit(f"bench_scaling --mesh: asked for {args.devices} "
+                     f"devices, the {devices[0].platform} backend has "
+                     f"{len(devices)}; pass --devices {len(devices)}")
+        counts = tuple(2 ** k for k in range(args.devices.bit_length())
+                       if 2 ** k <= args.devices)
+        main_mesh(device_counts=counts)
     else:
         main()

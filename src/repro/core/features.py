@@ -29,6 +29,8 @@ import math
 import jax
 import jax.numpy as jnp
 
+from ..kernels.tiling import F32_PRODUCTS
+
 __all__ = [
     "lambert_w0",
     "gaussian_q",
@@ -159,7 +161,7 @@ def gaussian_log_features(
     r = anchors.shape[0]
     x2 = jnp.sum(x * x, axis=-1, keepdims=True)            # (n, 1)
     u2 = jnp.sum(anchors * anchors, axis=-1)[None, :]       # (1, r)
-    xu = x @ anchors.T                                      # (n, r)  MXU
+    xu = jnp.matmul(x, anchors.T, precision=F32_PRODUCTS)  # (n, r)  MXU
     sqdist = x2 + u2 - 2.0 * xu
     logphi = _anchor_log_const(anchors, q, eps)[None, :] - 2.0 / eps * sqdist
     if include_sqrt_r:
@@ -206,7 +208,7 @@ def arccos_features(
     """
     n = x.shape[0]
     r, d = anchors.shape
-    proj = x @ anchors.T                                    # (n, r)
+    proj = jnp.matmul(x, anchors.T, precision=F32_PRODUCTS)  # (n, r)
     rect = jnp.maximum(proj, 0.0) ** s if s > 0 else (proj > 0).astype(x.dtype)
     u2 = jnp.sum(anchors * anchors, axis=-1)[None, :]
     damp = jnp.exp(-0.25 * u2 * (1.0 - 1.0 / (sigma * sigma)))

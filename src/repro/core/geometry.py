@@ -60,7 +60,7 @@ import jax
 import jax.numpy as jnp
 
 from ..kernels.ops import check_precision
-from ..kernels.tiling import compute_f32
+from ..kernels.tiling import F32_PRODUCTS, compute_f32
 from .features import (
     arccos_features,
     gaussian_log_features,
@@ -95,7 +95,7 @@ def squared_euclidean(x: jax.Array, y: jax.Array) -> jax.Array:
     """C_ij = ||x_i - y_j||^2, shapes (n,d),(m,d) -> (n,m)."""
     x2 = jnp.sum(x * x, axis=-1)[:, None]
     y2 = jnp.sum(y * y, axis=-1)[None, :]
-    C = x2 + y2 - 2.0 * (x @ y.T)
+    C = x2 + y2 - 2.0 * _matmul(x, y.T)
     return jnp.maximum(C, 0.0)
 
 
@@ -146,6 +146,14 @@ def _compute(arr: jax.Array) -> jax.Array:
     of :func:`repro.kernels.tiling.compute_f32` — the kernels' register
     upcast — so the rule has one implementation."""
     return compute_f32(arr)
+
+
+def _matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` with f32 products (:data:`~repro.kernels.tiling.
+    F32_PRODUCTS`): a vmapped matvec (``solve_many``, the service's
+    runners) lowers to the MXU, which would otherwise round the scalings
+    to bf16."""
+    return jnp.matmul(a, b, precision=F32_PRODUCTS)
 
 
 def _factored_log_apply(log_u: jax.Array, log_w: jax.Array,
@@ -356,8 +364,8 @@ class _FeatureKernelOps:
 
     def operators(self, *, precision: str = "highest"):
         xi, zeta = (_stored(w, precision) for w in self.features())
-        return (lambda v: _compute(xi) @ (_compute(zeta).T @ v),
-                lambda u: _compute(zeta) @ (_compute(xi).T @ u))
+        return (lambda v: _matmul(_compute(xi), _matmul(_compute(zeta).T, v)),
+                lambda u: _matmul(_compute(zeta), _matmul(_compute(xi).T, u)))
 
     def log_operators(self, *, precision: str = "highest"):
         eps = self.eps
@@ -410,7 +418,8 @@ class DenseCost(Geometry):
     def operators(self, *, precision: str = "highest"):
         # materialized ONCE per solve (bf16 storage under the policy)
         K = _stored(jnp.exp(-self.C / self.eps), precision)
-        return (lambda v: _compute(K) @ v), (lambda u: _compute(K).T @ u)
+        return (lambda v: _matmul(_compute(K), v),
+                lambda u: _matmul(_compute(K).T, u))
 
     def log_operators(self, *, precision: str = "highest"):
         eps = self.eps
@@ -720,8 +729,8 @@ class NystromLowRank(Geometry):
 
     def operators(self, *, precision: str = "highest"):
         L, Rt = _stored(self.L, precision), _stored(self.Rt, precision)
-        return (lambda v: _compute(L) @ (_compute(Rt) @ v),
-                lambda u: _compute(Rt).T @ (_compute(L).T @ u))
+        return (lambda v: _matmul(_compute(L), _matmul(_compute(Rt), v)),
+                lambda u: _matmul(_compute(Rt).T, _matmul(_compute(L).T, u)))
 
     def apply_k(self, v):
         return self.L @ (self.Rt @ v)
@@ -816,7 +825,8 @@ class GridSeparable(Geometry):
         """d axis-wise contractions: one small (n_k, m_k) matmul per axis."""
         V = v.reshape(grid)
         for k, Mk in enumerate(mats):
-            V = jnp.moveaxis(jnp.tensordot(Mk, V, axes=(1, k)), 0, k)
+            V = jnp.moveaxis(jnp.tensordot(
+                Mk, V, axes=(1, k), precision=F32_PRODUCTS), 0, k)
         return V.reshape(-1)
 
     @staticmethod

@@ -55,7 +55,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..distributed.sharding import psum_logsumexp, shard_map
+from ..distributed.sharding import psum_logsumexp
 from .api import _pad_rows
 from .geometry import (
     ArcCosinePointCloud,
@@ -63,6 +63,7 @@ from .geometry import (
     GaussianPointCloud,
     Geometry,
     _compute,
+    _matmul,
     _register,
     _stored,
 )
@@ -97,10 +98,12 @@ def _psum_factored_ops(xi, zeta, axis: str) -> Tuple[Callable, Callable]:
     the local contraction and the psum'd r-vector stay f32."""
 
     def apply_k(v):                              # (m/p,) -> (n/p,)
-        return _compute(xi) @ jax.lax.psum(_compute(zeta).T @ v, axis)
+        return _matmul(_compute(xi),
+                       jax.lax.psum(_matmul(_compute(zeta).T, v), axis))
 
     def apply_kt(u):                             # (n/p,) -> (m/p,)
-        return _compute(zeta) @ jax.lax.psum(_compute(xi).T @ u, axis)
+        return _matmul(_compute(zeta),
+                       jax.lax.psum(_matmul(_compute(xi).T, u), axis))
 
     return apply_k, apply_kt
 
@@ -499,7 +502,7 @@ def sharded_sinkhorn_geometry(
             precision=precision,
         )
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=geom_specs + (P(axis), P(axis), P(axis), P(axis)),
         out_specs=_result_specs(axis),
@@ -544,7 +547,7 @@ def sharded_sinkhorn_divergence(
         return _divergence_body(geom_local, la, lb, axis=axis, tol=tol,
                                 max_iter=max_iter)
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=geom_specs + (P(axis), P(axis)),
         out_specs=P(),

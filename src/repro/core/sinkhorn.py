@@ -63,7 +63,9 @@ from ..kernels.ops import (
     relax_log,
     relax_scaling,
 )
-from .geometry import DenseCost, FactoredPositive, Geometry, _masked_log
+from .geometry import (
+    DenseCost, FactoredPositive, Geometry, _masked_log, _matmul,
+)
 
 __all__ = [
     "SinkhornResult",
@@ -300,7 +302,7 @@ def _maybe_pallas_plan(geom: Geometry, use_pallas: Optional[bool],
     the XLA operators. ``True`` forces the plan (interpret mode on CPU —
     the test configuration), ``False`` forces the XLA operators.
     Geometries without a fused plan (dense, Nystrom, grids) always fall
-    back. Selections are reported through the
+    back. :func:`_plan_loop` reports the selection through the
     ``kernels.ops.observe_plan_selection`` hook.
     """
     if geom.spmd_axis is not None:
@@ -312,15 +314,7 @@ def _maybe_pallas_plan(geom: Geometry, use_pallas: Optional[bool],
         use_pallas = not resolve_backend().interpret
     if not use_pallas:
         return None
-    plan = geometry_ops(geom, mode=mode, precision=precision)
-    if plan is not None:
-        notify_plan_selected({
-            "geometry": type(geom).__name__,
-            "mode": plan.mode,
-            "kind": plan.kind,
-            "precision": plan.precision,
-        })
-    return plan
+    return geometry_ops(geom, mode=mode, precision=precision)
 
 
 def _resolve_cadence(plan, inner_steps: Optional[int],
@@ -358,18 +352,27 @@ def _resolve_cadence(plan, inner_steps: Optional[int],
     return inner, check, False
 
 
-def _plan_loop(plan, step_args, *, tol, max_iter, dtype,
+def _plan_loop(plan, step_args, *, geometry, tol, max_iter, dtype,
                inner_steps, check_every, momentum):
     """Shared hot-loop driver for both fused-plan modes: resolve the
     cadence, prefer the persistent megakernel block step (``inner_steps``
     iterations per launch, carries on-chip), fall back to the streaming
-    per-iteration step at the same check cadence."""
+    per-iteration step at the same check cadence. Reports the selection
+    (``step`` = "megakernel" | "per_iteration") to the plan hook."""
     a, b = step_args
     inner, check, auto = _resolve_cadence(plan, inner_steps, check_every)
     block = None
     if inner > 1 and plan.make_block_step is not None:
         block = plan.make_block_step(a, b, inner_steps=inner,
                                      momentum=momentum)
+    notify_plan_selected({
+        "geometry": geometry,
+        "mode": plan.mode,
+        "kind": plan.kind,
+        "precision": plan.precision,
+        "interpret": plan.interpret,
+        "step": "per_iteration" if block is None else "megakernel",
+    })
     if block is not None:
         step, init = block
         return init, functools.partial(
@@ -394,8 +397,8 @@ def _finish_scaling(a, b, u, v, it, err, *, eps, tol,
     return SinkhornResult(u, v, f, g, cost, it, err, err <= tol)
 
 
-def _solve_scaling_plan(plan, a, b, *, eps, tol, max_iter, momentum,
-                        u_init, inner_steps=None,
+def _solve_scaling_plan(plan, a, b, *, geometry, eps, tol, max_iter,
+                        momentum, u_init, inner_steps=None,
                         check_every=None) -> SinkhornResult:
     """Alg. 1 with the ``lax.while_loop`` body routed through the fused
     Pallas plan — semantics (masking, warm start, marginal check, momentum)
@@ -407,8 +410,9 @@ def _solve_scaling_plan(plan, a, b, *, eps, tol, max_iter, momentum,
     u0 = jnp.ones((n,), dtype) if u_init is None else u_init
     v0 = jnp.ones((m,), dtype)
     init, loop = _plan_loop(
-        plan, (a, b), tol=tol, max_iter=max_iter, dtype=dtype,
-        inner_steps=inner_steps, check_every=check_every, momentum=momentum,
+        plan, (a, b), geometry=geometry, tol=tol, max_iter=max_iter,
+        dtype=dtype, inner_steps=inner_steps, check_every=check_every,
+        momentum=momentum,
     )
     it, (u, v, _), err = loop(init(u0, v0))
     return _finish_scaling(a, b, u, v, it, err, eps=eps, tol=tol)
@@ -503,9 +507,9 @@ def sinkhorn_geometry(
     plan = _maybe_pallas_plan(geom, use_pallas, "scaling", precision)
     if plan is not None:
         return _solve_scaling_plan(
-            plan, a, b, eps=geom.eps, tol=tol, max_iter=max_iter,
-            momentum=momentum, u_init=u_init, inner_steps=inner_steps,
-            check_every=check_every,
+            plan, a, b, geometry=type(geom).__name__, eps=geom.eps,
+            tol=tol, max_iter=max_iter, momentum=momentum, u_init=u_init,
+            inner_steps=inner_steps, check_every=check_every,
         )
     _, check, _ = _resolve_cadence(None, inner_steps, check_every)
     matvec, rmatvec = geom.operators(precision=precision)
@@ -548,7 +552,7 @@ def sinkhorn_quadratic(
 ) -> SinkhornResult:
     """The paper's ``Sin`` baseline (Cuturi '13): dense O(nm) matvecs."""
     return sinkhorn_operator(
-        lambda v: K @ v, lambda u: K.T @ u, a, b,
+        lambda v: _matmul(K, v), lambda u: _matmul(K.T, u), a, b,
         eps=eps, tol=tol, max_iter=max_iter, momentum=momentum, u_init=u_init,
     )
 
@@ -594,7 +598,8 @@ def sinkhorn_log_geometry(
     plan = _maybe_pallas_plan(geom, use_pallas, "log", precision)
     if plan is not None:
         return _solve_log_plan(
-            plan, a, b, eps=geom.eps, tol=tol, max_iter=max_iter,
+            plan, a, b, geometry=type(geom).__name__, eps=geom.eps,
+            tol=tol, max_iter=max_iter,
             momentum=momentum, f_init=f_init, g_init=g_init,
             inner_steps=inner_steps, check_every=check_every,
         )
@@ -651,7 +656,7 @@ def _log_domain_solve(
                        reduce=err_reduce)
 
 
-def _solve_log_plan(plan, a, b, *, eps, tol, max_iter, momentum,
+def _solve_log_plan(plan, a, b, *, geometry, eps, tol, max_iter, momentum,
                     f_init, g_init, inner_steps=None,
                     check_every=None) -> SinkhornResult:
     """Log-domain solve with the while_loop body routed through the fused
@@ -660,8 +665,9 @@ def _solve_log_plan(plan, a, b, *, eps, tol, max_iter, momentum,
     the check cadence."""
     f0, g0, dtype = _log_init(a, b, f_init, g_init)
     init, loop = _plan_loop(
-        plan, (a, b), tol=tol, max_iter=max_iter, dtype=dtype,
-        inner_steps=inner_steps, check_every=check_every, momentum=momentum,
+        plan, (a, b), geometry=geometry, tol=tol, max_iter=max_iter,
+        dtype=dtype, inner_steps=inner_steps, check_every=check_every,
+        momentum=momentum,
     )
     it, (f, g, _), err = loop(init(f0, g0))
     return _finish_log(a, b, f, g, it, err, eps=eps, tol=tol)

@@ -30,7 +30,6 @@ __all__ = [
     "current_mesh_context",
     "psum_logsumexp",
     "shard",
-    "shard_map",
     "logical_spec",
 ]
 
@@ -58,26 +57,6 @@ def psum_logsumexp(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.Array:
     local_sum = jnp.sum(jnp.exp(x - jnp.expand_dims(shift, axis)), axis=axis)
     return shift + jnp.log(jax.lax.psum(local_sum, axis_name))
 
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``.
-
-    Newer jax exposes ``jax.shard_map`` (with ``check_vma``); 0.4.x only has
-    ``jax.experimental.shard_map.shard_map`` (with ``check_rep``). Every
-    shard_map in this repo routes through here so the SPMD solvers and the
-    multi-device tests run on both.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
-    )
 
 _state = threading.local()
 

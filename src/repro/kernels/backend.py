@@ -57,14 +57,19 @@ __all__ = [
 
 BACKEND_ENV = "REPRO_BACKEND"
 
-# Megakernel (whole-array persistent block) admission budgets. TPU: VMEM is
-# ~16 MiB/core; 12 MiB leaves double-buffering headroom. GPU: a Triton
-# pallas_call with no grid is one CTA whose whole working set must sit in
-# shared memory / registers — 192 KiB covers an H100 SM with headroom, so
-# only genuinely tiny problems are admitted and everything else refuses
-# into the streaming per-iteration plan. Interpret: no real memory bound;
-# the cap only guards against accidentally materializing huge arrays.
-MEGAKERNEL_BUDGET_TPU = 12 * 2**20
+# Megakernel (whole-array persistent block) admission budgets, compared
+# with ``fused_loop.block_vmem_bytes``. TPU: the kernel's scoped-VMEM limit
+# is set to this same number (v5e has 128 MiB of VMEM; operands of an
+# unbatched launch sit in VMEM beside the scoped stack, and the count's
+# double-buffered inputs and outputs bound them to half the budget). At
+# r = 256 it admits n = m = 2048 in f32 and bf16 and refuses 4096. GPU:
+# a Triton pallas_call with no grid is one CTA whose whole working set
+# must sit in shared memory / registers — 192 KiB covers an H100 SM, which
+# the lane-padded count exceeds at every shape, so the GPU lane always
+# refuses into the streaming per-iteration plan. Interpret: no real memory
+# bound; the cap only guards against accidentally materializing huge
+# arrays.
+MEGAKERNEL_BUDGET_TPU = 40 * 2**20
 MEGAKERNEL_BUDGET_GPU = 192 * 2**10
 MEGAKERNEL_BUDGET_INTERPRET = 512 * 2**20
 

@@ -40,7 +40,7 @@ from jax.experimental import pallas as pl
 
 from . import autotune
 from .backend import Backend
-from .tiling import pad_axis
+from .tiling import F32_PRODUCTS, pad_axis
 
 __all__ = ["gaussian_feature_map_kernel", "gaussian_feature_map_pallas"]
 
@@ -61,6 +61,7 @@ def gaussian_feature_map_kernel(
         x_ref[...],
         u_ref[...],
         (((1,), (1,)), ((), ())),
+        precision=F32_PRODUCTS,
         preferred_element_type=jnp.float32,
     )
 

@@ -30,22 +30,25 @@ upcast feature tiles to f32 in registers; every contraction and LSE
 accumulates in f32.
 
 On CPU (CI) the kernels run in ``interpret=True`` mode; on TPU the same
-bodies compile to Mosaic. ``relax_scaling`` / ``relax_log`` are canonical
+bodies compile to Mosaic under a scoped-VMEM limit equal to the backend
+record's admission budget. ``relax_scaling`` / ``relax_log`` are canonical
 here (shared with the XLA solvers through ``kernels.ops``) so this module
 stays import-cycle-free.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from .backend import Backend
 from .logmatvec import _finite_or_zero
-from .tiling import LANE, compute_f32 as _f32, pad_axis, round_up
+from .tiling import (F32_PRODUCTS, LANE, compute_f32 as _f32, pad_axis,
+                     round_up)
 
 __all__ = [
     "relax_scaling",
@@ -58,15 +61,6 @@ __all__ = [
 
 # sublane quantum covering both f32 (8) and bf16 (16) second-to-minor dims
 _SUBLANE_ANY = 16
-
-# Legacy working-set ceilings for the whole-array megakernel, used when no
-# Backend record is supplied (the interpret-flag compat surface). The
-# canonical per-backend budgets live in ``kernels.backend`` — TPU's 12 MiB
-# VMEM (double-buffering headroom under ~16 MiB/core), GPU's 192 KiB
-# shared-memory bound (a gridless Triton pallas_call is ONE CTA), and the
-# interpret guard against accidentally materializing huge arrays.
-VMEM_BUDGET_COMPILED = 12 * 2**20
-VMEM_BUDGET_INTERPRET = 512 * 2**20
 
 
 # ---------------------------------------------------------------------------
@@ -108,39 +102,55 @@ def relax_log(new: jax.Array, old: jax.Array, momentum: float) -> jax.Array:
 
 def block_vmem_bytes(n: int, m: int, r: int, B: int = 1,
                      feature_dtype=jnp.float32) -> int:
-    """Working-set bytes of one megakernel launch (padded shapes).
+    """VMEM bytes one megakernel launch may need, counted as Mosaic lays
+    the operands out (measured against the v5e compiler, which
+    ``tests/test_tpu_compile.py`` keeps honest).
 
-    Factors dominate: (n + m) * r at the feature storage width; the
-    carried vectors and intermediates are O((n + m + r) * B) f32 — B
-    stays UNPADDED in both megakernels (B = 1 on the solver path;
-    batching rides the vmap grid axis).
+    * A carried (rows, B) f32 vector is tiled ``T(8, 128)``: its minor
+      B axis pads to a full lane, so each row costs ``round_up(B, 128) * 4``
+      bytes -- 128x the unpadded count at B = 1.
+    * The kernel loads every factor tile as f32 (``_f32``), so bf16 factors
+      need an f32 working copy on top of their storage.
+    * Launched under ``vmap`` (solve_many, the service's bucket runners)
+      the call gains a grid axis and every operand block is double
+      buffered, so the inputs and outputs count twice.
+
+    Per launch: 3 n-length and 5 m-length carries in and out (the log twin
+    has one fewer m-carry but two (r, B) stage-1 LSE carries), plus about
+    three vectors' worth of loop temporaries per support row.
     """
     np_, mp = round_up(n, _SUBLANE_ANY), round_up(m, _SUBLANE_ANY)
-    rp = round_up(r, LANE)
-    fbytes = jnp.dtype(feature_dtype).itemsize
-    factors = (np_ + mp) * rp * fbytes
-    vectors = (3 * np_ + 4 * mp + 2 * rp) * B * 4
-    return factors + vectors
+    rows, rp = np_ + mp, round_up(r, LANE)
+    vec_row = round_up(B, LANE) * 4
+    factors = rows * rp * jnp.dtype(feature_dtype).itemsize
+    io = factors + (3 * np_ + 5 * mp + 2 * rp) * vec_row
+    work = rows * rp * 4 + 3 * rows * vec_row
+    return 2 * io + work
 
 
 def block_plan_fits(n: int, m: int, r: int, B: int = 1,
-                    feature_dtype=jnp.float32,
-                    interpret: bool = False,
-                    backend: Optional[Backend] = None) -> bool:
-    """Whether the whole-array megakernel is admissible at this shape.
+                    feature_dtype=jnp.float32, *,
+                    backend: Backend) -> bool:
+    """Whether the whole-array megakernel is admissible at this shape: the
+    :class:`~repro.kernels.backend.Backend` record's budget bounds
+    :func:`block_vmem_bytes`, and records whose megakernel lowering is
+    disabled refuse outright. On tpu-mosaic the same budget is the
+    compiler's scoped-VMEM limit (:func:`_call_params`)."""
+    return (backend.megakernel
+            and block_vmem_bytes(n, m, r, B, feature_dtype)
+            <= backend.block_budget)
 
-    With a :class:`~repro.kernels.backend.Backend` record the admission
-    gate is the record's own budget — 12 MiB VMEM on tpu-mosaic, 192 KiB
-    shared memory on gpu-triton (one CTA holds the whole working set), a
-    materialization guard on interpret — and backends whose megakernel
-    lowering is disabled refuse outright. Without a record the legacy
-    interpret-flag behavior applies (compat surface for existing call
-    sites and tests)."""
-    bytes_ = block_vmem_bytes(n, m, r, B, feature_dtype)
-    if backend is not None:
-        return backend.megakernel and bytes_ <= backend.block_budget
-    budget = VMEM_BUDGET_INTERPRET if interpret else VMEM_BUDGET_COMPILED
-    return bytes_ <= budget
+
+def _call_params(backend: Backend) -> dict:
+    """``pallas_call`` options of one megakernel launch. On Mosaic the
+    scoped-VMEM limit IS the record's admission budget, so the bytes
+    :func:`block_plan_fits` admits are the bytes the compiler may use."""
+    if backend.interpret:
+        return dict(interpret=True)
+    if backend.name == "tpu-mosaic":
+        return dict(compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=backend.block_budget))
+    return {}
 
 
 def _pad_rows_rep(arr: jax.Array, mult: int) -> jax.Array:
@@ -165,14 +175,16 @@ def _pad_rows_rep(arr: jax.Array, mult: int) -> jax.Array:
 def _contract(w: jax.Array, x: jax.Array) -> jax.Array:
     """(n, r)^T @ (n, B) -> (r, B), f32 accumulation."""
     return jax.lax.dot_general(
-        w, x, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        w, x, (((0,), (0,)), ((), ())), precision=F32_PRODUCTS,
+        preferred_element_type=jnp.float32,
     )
 
 
 def _matvec(w: jax.Array, t: jax.Array) -> jax.Array:
     """(n, r) @ (r, B) -> (n, B), f32 accumulation."""
     return jax.lax.dot_general(
-        w, t, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32,
+        w, t, (((1,), (0,)), ((), ())), precision=F32_PRODUCTS,
+        preferred_element_type=jnp.float32,
     )
 
 
@@ -212,11 +224,11 @@ def _block_kernel(xi_ref, zeta_ref, a_ref, b_ref, u0_ref, v0_ref, s0_ref,
     u_ref[...] = u
     v_ref[...] = v
     s_ref[...] = s
-    err_ref[0, 0] = jnp.sum(jnp.abs(v * s - b))
+    err_ref[...] = jnp.sum(jnp.abs(v * s - b), keepdims=True)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("inner_steps", "momentum", "interpret")
+    jax.jit, static_argnames=("inner_steps", "momentum", "backend")
 )
 def sinkhorn_block_pallas(
     xi: jax.Array,          # (n, r) features (f32 or bf16 storage)
@@ -229,7 +241,7 @@ def sinkhorn_block_pallas(
     *,
     inner_steps: int,
     momentum: float = 1.0,
-    interpret: bool = False,
+    backend: Backend,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One megakernel block: ``inner_steps`` scaling-space iterations.
 
@@ -260,7 +272,7 @@ def sinkhorn_block_pallas(
             jax.ShapeDtypeStruct((mpad, B), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
-        interpret=interpret,
+        **_call_params(backend),
     )(xp, zp, ap, bp, up, vp, sp)
     return u[:n], v[:m], s[:m], err[0, 0]
 
@@ -332,11 +344,12 @@ def _log_block_kernel(lxi_ref, lzt_ref, loga_ref, logb_ref, b_ref,
     g_ref[...] = g
     t_ref[...] = t
     log_col = _lse_rows(lzt, t, n_cols) + g / eps
-    err_ref[0, 0] = jnp.sum(jnp.abs(jnp.exp(log_col) - b_ref[...]))
+    err_ref[...] = jnp.sum(jnp.abs(jnp.exp(log_col) - b_ref[...]),
+                           keepdims=True)
 
 
 @functools.partial(
-    jax.jit, static_argnames=("inner_steps", "eps", "momentum", "interpret")
+    jax.jit, static_argnames=("inner_steps", "eps", "momentum", "backend")
 )
 def log_sinkhorn_block_pallas(
     log_xi: jax.Array,      # (n, r) log-features (f32 or bf16 storage)
@@ -351,7 +364,7 @@ def log_sinkhorn_block_pallas(
     inner_steps: int,
     eps: float,
     momentum: float = 1.0,
-    interpret: bool = False,
+    backend: Backend,
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One megakernel block: ``inner_steps`` log-domain iterations.
 
@@ -389,6 +402,6 @@ def log_sinkhorn_block_pallas(
             jax.ShapeDtypeStruct((rpad, B), jnp.float32),
             jax.ShapeDtypeStruct((1, 1), jnp.float32),
         ),
-        interpret=interpret,
+        **_call_params(backend),
     )(xp, zp, lap, lbp, bp, fp, gp, tp)
     return f[:n], g[:m], t[:r], err[0, 0]

@@ -41,7 +41,7 @@ from jax.experimental import pallas as pl
 
 from . import autotune
 from .backend import Backend
-from .tiling import LANE, compute_f32 as _f32, pad_axis
+from .tiling import F32_PRODUCTS, LANE, compute_f32 as _f32, pad_axis
 
 __all__ = [
     "feature_contract_pallas",
@@ -62,6 +62,7 @@ def _feature_contract_kernel(xi_ref, u_ref, t_ref):
         _f32(xi_ref[...]),
         u_ref[...],
         (((0,), (0,)), ((), ())),          # contract the n axis
+        precision=F32_PRODUCTS,
         preferred_element_type=jnp.float32,
     )
 
@@ -74,6 +75,7 @@ def _feature_contract_splitk_kernel(xi_ref, u_ref, t_ref):
         _f32(xi_ref[...]),
         u_ref[...],
         (((0,), (0,)), ((), ())),
+        precision=F32_PRODUCTS,
         preferred_element_type=jnp.float32,
     )[None]
 
@@ -170,6 +172,7 @@ def _halfstep_kernel(xi_ref, t_ref, marg_ref, o_ref):
         _f32(xi_ref[...]),
         t_ref[...],
         (((1,), (0,)), ((), ())),
+        precision=F32_PRODUCTS,
         preferred_element_type=jnp.float32,
     )
     o_ref[...] = marg_ref[...] / kv
@@ -181,6 +184,7 @@ def _matvec_kernel(xi_ref, t_ref, o_ref):
         _f32(xi_ref[...]),
         t_ref[...],
         (((1,), (0,)), ((), ())),
+        precision=F32_PRODUCTS,
         preferred_element_type=jnp.float32,
     )
 

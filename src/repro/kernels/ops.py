@@ -437,8 +437,7 @@ def _scaling_plan(kind: str, xi, zeta, be: Backend,
             u, v, s = carry
             u2, v2, s2, err = sinkhorn_block_pallas(
                 xi, zeta, ac, bc, u[:, None], v[:, None], s[:, None],
-                inner_steps=inner_steps, momentum=momentum,
-                interpret=be.interpret,
+                inner_steps=inner_steps, momentum=momentum, backend=be,
             )
             return (u2[:, 0], v2[:, 0], s2[:, 0]), err
 
@@ -512,7 +511,7 @@ def _log_plan(kind: str, log_xi, log_zeta, eps: float, be: Backend,
                 log_xi, log_zeta, loga, logb, bc,
                 f[:, None], g[:, None], t1,
                 inner_steps=inner_steps, eps=eps, momentum=momentum,
-                interpret=be.interpret,
+                backend=be,
             )
             return (f2[:, 0], g2[:, 0], t2), err
 
@@ -689,7 +688,9 @@ def notify_plan_selected(event: dict) -> None:
 def observe_plan_selection():
     """Collect plan-selection events: ``with observe_plan_selection() as ev:
     solve(...)`` then assert on ``ev`` (list of dicts with ``geometry`` /
-    ``mode`` / ``kind`` keys)."""
+    ``mode`` / ``kind`` / ``precision`` / ``interpret`` keys, and ``step``:
+    "megakernel" for the persistent block step, "per_iteration" for the
+    streaming plan)."""
     events: List[dict] = []
     _PLAN_OBSERVERS.append(events.append)
     try:

@@ -43,7 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from .backend import Backend
-from .tiling import LANE, compute_f32 as _f32, pad_axis
+from .tiling import F32_PRODUCTS, LANE, compute_f32 as _f32, pad_axis
 
 __all__ = [
     "paged_feature_contract_pallas",
@@ -97,6 +97,7 @@ def _paged_contract_kernel(live_ref, xi_ref, u_ref, t_ref):
             _f32(xi_ref[...]),
             u_ref[...],
             (((0,), (0,)), ((), ())),          # contract the page-row axis
+            precision=F32_PRODUCTS,
             preferred_element_type=jnp.float32,
         )
 
@@ -167,6 +168,7 @@ def _paged_halfstep_kernel(live_ref, xi_ref, t_ref, marg_ref, o_ref):
             _f32(xi_ref[...]),
             t_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=F32_PRODUCTS,
             preferred_element_type=jnp.float32,
         )
         o_ref[...] = marg_ref[...] / kv
@@ -187,6 +189,7 @@ def _paged_matvec_kernel(live_ref, xi_ref, t_ref, o_ref):
             _f32(xi_ref[...]),
             t_ref[...],
             (((1,), (0,)), ((), ())),
+            precision=F32_PRODUCTS,
             preferred_element_type=jnp.float32,
         )
 
@@ -282,10 +285,10 @@ def paged_contract_ref(xi, u, page_live, *, page_size: int) -> jax.Array:
     C, r = xi.shape
     n_pages = C // page_size
     mask = jnp.repeat((page_live > 0).astype(xi.dtype), page_size)
-    return _f32(xi).T @ (u * mask[:, None])
+    return jnp.matmul(_f32(xi).T, u * mask[:, None], precision=F32_PRODUCTS)
 
 
 def paged_matvec_ref(xi, t, page_live, *, page_size: int) -> jax.Array:
     """Masked XLA twin of :func:`paged_feature_matvec_pallas`."""
     mask = jnp.repeat((page_live > 0).astype(xi.dtype), page_size)
-    return (_f32(xi) @ t) * mask[:, None]
+    return jnp.matmul(_f32(xi), t, precision=F32_PRODUCTS) * mask[:, None]

@@ -7,6 +7,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+from .tiling import F32_PRODUCTS
+
 __all__ = [
     "gaussian_feature_map_ref",
     "feature_contract_ref",
@@ -34,14 +36,14 @@ def gaussian_feature_map_ref(
     constraint on parallel-grid backends; see ``kernels.backend``)."""
     x2 = jnp.sum(x * x, axis=-1)[:, None]
     u2 = jnp.sum(anchors * anchors, axis=-1)[None, :]
-    sq = x2 + u2 - 2.0 * (x @ anchors.T)
+    sq = x2 + u2 - 2.0 * jnp.matmul(x, anchors.T, precision=F32_PRODUCTS)
     log_xi = log_const[None, :] - 2.0 * inv_eps * sq
     return log_xi if log_space else jnp.exp(log_xi)
 
 
 def feature_contract_ref(xi: jax.Array, u: jax.Array) -> jax.Array:
     """t = Xi^T u : (n, r), (n, B) -> (r, B). Phase 1 of a Sinkhorn half-step."""
-    return xi.T @ u
+    return jnp.matmul(xi.T, u, precision=F32_PRODUCTS)
 
 
 def sinkhorn_halfstep_ref(
@@ -50,13 +52,13 @@ def sinkhorn_halfstep_ref(
     marg: jax.Array,       # (n, B) target marginal
 ) -> jax.Array:
     """out = marg / (Xi @ t) : the fused matvec + marginal divide."""
-    return marg / (xi @ t)
+    return marg / jnp.matmul(xi, t, precision=F32_PRODUCTS)
 
 
 def feature_matvec_ref(xi: jax.Array, t: jax.Array) -> jax.Array:
     """out = Xi @ t : (n, r), (r, B) -> (n, B). The divide-free twin of
     :func:`sinkhorn_halfstep_ref` (marginal-check matvec)."""
-    return xi @ t
+    return jnp.matmul(xi, t, precision=F32_PRODUCTS)
 
 
 def log_matvec_ref(log_m: jax.Array, t: jax.Array) -> jax.Array:

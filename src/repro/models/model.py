@@ -215,9 +215,7 @@ def _moe_apply(p, x2: jax.Array, cfg: ArchConfig) -> Tuple[jax.Array, jax.Array]
         P(dp, tp, None),
     )
     out_specs = (P(dp, tp, None), P())
-    from ..distributed.sharding import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,
     )

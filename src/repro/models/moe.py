@@ -121,7 +121,7 @@ def moe_ep_local(
     (E_loc, d, f) x3 to (d, f) x3 (§Perf train-memory hillclimb).
     """
     T, d = x.shape
-    n_ranks = jax.lax.psum(1, axis)     # portable axis size (0.4.x has no lax.axis_size)
+    n_ranks = jax.lax.axis_size(axis)
     E_loc = n_experts // n_ranks
     combine, aux = router_probs(p_local, x, top_k=top_k, router=router,
                                 policy=policy)

@@ -159,9 +159,7 @@ def ssd_context_parallel(
             jnp.exp(jnp.moveaxis(dacs, 1, 2)), init.astype(x_l.dtype))
         return y0 + y_corr.astype(y0.dtype)
 
-    from ..distributed.sharding import shard_map
-
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(dp, tp, None, None), P(dp, tp, None),
                   P(dp, tp, None), P(dp, tp, None)),

@@ -57,6 +57,30 @@ __all__ = ["Ticket", "OTService", "Refusal", "QuarantineError",
            "QueueFullError"]
 
 
+_RESULT_FIELDS = ("u", "v", "f", "g", "cost", "n_iter", "marginal_err",
+                  "converged")
+
+
+class _RunnerFault(RuntimeError):
+    """An exception raised while a COMPILED runner executed (or by the
+    chaos hook just before it) — the only kind recovery absorbs. Errors
+    from building, lowering or compiling a runner are never wrapped, so
+    they propagate instead of turning into a recovered request."""
+
+
+def _execute(runner, arrays, hook: Optional[Callable] = None,
+             cell=None) -> Dict[str, np.ndarray]:
+    """Run one compiled megabatch and pull it to the host in one transfer;
+    failures surface as :class:`_RunnerFault`."""
+    try:
+        if hook is not None:
+            hook(*cell)
+        res = runner.run(*arrays)
+        return {k: np.asarray(getattr(res, k)) for k in _RESULT_FIELDS}
+    except Exception as exc:
+        raise _RunnerFault(f"{type(exc).__name__}: {exc}") from exc
+
+
 class QuarantineError(RuntimeError):
     """Submit-time refusal of a quarantined repeat-offender fingerprint
     (a request that has already exhausted the recovery ladder
@@ -459,27 +483,23 @@ class OTService:
             else:
                 f0s.append(_pad_np(it.f0, shape.n_pad, replicate=False))
                 g0s.append(_pad_np(it.g0, shape.m_pad, replicate=False))
+        # building the runner compiles it: errors there always propagate
         runner = self.runners.get(shape, b_pad)
+        arrays = tuple(np.stack(x) for x in (kas, kbs, aws, bws, f0s, g0s))
         try:
-            if self.chaos_hook is not None:
-                self.chaos_hook(shape, b_pad)
-            res = runner.run(np.stack(kas), np.stack(kbs), np.stack(aws),
-                             np.stack(bws), np.stack(f0s), np.stack(g0s))
-            # one device->host pull for the whole megabatch; per-request
-            # unpadding is then pure numpy slicing
-            host = {k: np.asarray(getattr(res, k))
-                    for k in ("u", "v", "f", "g", "cost", "n_iter",
-                              "marginal_err", "converged")}
-        except Exception as exc:
-            # infrastructure fault (chaos injection, a runner raising):
+            # per-request unpadding of ``host`` is pure numpy slicing
+            host = _execute(runner, arrays, self.chaos_hook, (shape, b_pad))
+        except _RunnerFault as exc:
+            # runtime fault (chaos injection, a compiled runner raising):
             # with recovery enabled the megabatch is absorbed — every
             # request retries solo through the ladder, starting with a
-            # cold re-run of the base config — otherwise it propagates
+            # cold re-run of the base config — otherwise the runner's own
+            # exception propagates
             if self.recovery is None:
-                raise
+                raise exc.__cause__
             self.runner_faults += 1
             for it in items:
-                self._recover_one(it, None, fault=exc)
+                self._recover_one(it, None, fault=exc.__cause__)
             self.served += b_real
             self.batches += 1
             return b_real
@@ -608,11 +628,8 @@ class OTService:
             pg = _pad_np(np.asarray(g0, np.float32), shape.m_pad,
                          replicate=False)
         runner = cache.get(shape, 1)
-        res = runner.run(pka[None], pkb[None], pa[None], pb[None],
-                         pf[None], pg[None])
-        host = {k: np.asarray(getattr(res, k))
-                for k in ("u", "v", "f", "g", "cost", "n_iter",
-                          "marginal_err", "converged")}
+        host = _execute(runner, tuple(x[None] for x in (pka, pkb, pa, pb,
+                                                        pf, pg)))
         return _unpad_np(host, 0, it.n, it.m)
 
     def _attempt(self, state: Dict[str, object], it: _Admitted,
@@ -670,7 +687,7 @@ class OTService:
             self.recovery_attempts += 1
             try:
                 r = self._attempt(state, it, stage)
-            except Exception:
+            except _RunnerFault:
                 self.runner_faults += 1
                 continue
             h = classify(r, a=it.a, b=it.b)

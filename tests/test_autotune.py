@@ -8,8 +8,7 @@ Contracts under test:
   precedence of explicit record/name > set_backend/scope >
   ``REPRO_BACKEND`` env > platform;
 * ``block_plan_fits`` reads its admission budget from the Backend record
-  (GPU gets the shared-memory gate, not TPU's 12 MiB VMEM constant) while
-  the positional legacy call keeps its interpret-flag behavior;
+  (GPU gets the shared-memory gate, not TPU's VMEM budget);
 * GPU plans never interpret: ``geometry_ops`` under a gpu backend yields
   ``interpret=False`` plans whose megakernel REFUSES (``make_block_step``
   -> None) beyond the SMEM budget, and the fused Gaussian map refuses into
@@ -147,27 +146,25 @@ def test_unknown_backend_name_raises():
 def test_block_plan_fits_reads_backend_budget():
     gpu = resolve_backend("gpu-triton")
     tpu = resolve_backend("tpu-mosaic")
-    # small problem: inside both budgets
-    assert block_plan_fits(64, 64, 32, backend=gpu)
+    interp = resolve_backend("interpret")
+    # small problem: inside the TPU's VMEM budget; the lane-padded carries
+    # alone exceed the 192 KiB SMEM gate
     assert block_plan_fits(64, 64, 32, backend=tpu)
-    # mid-size problem: fits 12 MiB VMEM, blows the 192 KiB SMEM gate
-    n, m, r = 4096, 4096, 256
-    assert block_vmem_bytes(n, m, r) > MEGAKERNEL_BUDGET_GPU
-    assert block_plan_fits(n, m, r, backend=tpu)
-    assert not block_plan_fits(n, m, r, backend=gpu)
+    assert block_vmem_bytes(64, 64, 32) > MEGAKERNEL_BUDGET_GPU
+    assert not block_plan_fits(64, 64, 32, backend=gpu)
+    # the largest admitted n = m bucket at r = 256, and the next one up
+    assert block_plan_fits(2048, 2048, 256, backend=tpu)
+    assert not block_plan_fits(4096, 4096, 256, backend=tpu)
+    assert block_plan_fits(4096, 4096, 256, backend=interp)
     # a record with megakernel lowering disabled refuses at ANY size
-    off = gpu._replace(megakernel=False)
+    off = tpu._replace(megakernel=False)
     assert not block_plan_fits(8, 8, 8, backend=off)
-    # legacy positional/interpret-flag surface unchanged
-    assert block_plan_fits(4096, 4096, 256, 1, jnp.float32, False)
-    assert not block_plan_fits(40960, 40960, 4096, 1, jnp.float32, False)
-    assert block_plan_fits(40960, 40960, 1024, 1, jnp.float32, True)
 
 
 def test_gpu_plan_metadata_never_interpret():
     """A geometry plan built for gpu-triton: interpret=False end to end,
     megakernel refuses beyond SMEM instead of interpreting."""
-    n, m, r = 4096, 4096, 256
+    n, m, r = 1024, 1024, 256
     xi = jax.random.uniform(KEY, (n, r)) + 0.05
     zt = jax.random.uniform(jax.random.fold_in(KEY, 1), (m, r)) + 0.05
     geom = FactoredPositive(xi=xi, zeta=zt, eps=0.5)

@@ -79,7 +79,6 @@ def test_moe_ep_multidevice_matches_dense():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.distributed.sharding import shard_map
         from repro.models.moe import init_moe, moe_dense, moe_ep_local
         key = jax.random.PRNGKey(0)
         T, d, f, E = 128, 16, 32, 8
@@ -87,7 +86,7 @@ def test_moe_ep_multidevice_matches_dense():
         x = jax.random.normal(jax.random.fold_in(key, 1), (T, d)) * 0.5
         out_d, _ = moe_dense(p, x, top_k=2)
         mesh = Mesh(np.array(jax.devices()).reshape(1, 8), ("data", "model"))
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda p_, x_: moe_ep_local(p_, x_, top_k=2, n_experts=E,
                                         axis="model", capacity_factor=8.0),
             mesh=mesh,
@@ -108,11 +107,10 @@ def test_compressed_psum_close_to_exact():
     _run("""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import Mesh, PartitionSpec as P
-        from repro.distributed.sharding import shard_map
         from repro.optim import compressed_psum
         mesh = Mesh(np.array(jax.devices()).reshape(8), ("data",))
         x = jax.random.normal(jax.random.PRNGKey(0), (8, 512)) * 0.1
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda v: (jax.lax.psum(v, "data"),
                        compressed_psum(v, "data")),
             mesh=mesh, in_specs=P("data", None),

@@ -27,6 +27,7 @@ from repro.core.geometry import (
     GaussianPointCloud,
 )
 from repro.kernels import fused_loop
+from repro.kernels.backend import resolve_backend
 from repro.kernels.ops import geometry_ops
 
 KEY = jax.random.PRNGKey(0)
@@ -297,15 +298,20 @@ def test_bf16_megakernel_block():
 def test_vmem_budget_policy():
     # the compiled budget refuses what real VMEM cannot hold; interpret
     # mode (CI/bench) gets headroom
-    assert fused_loop.block_plan_fits(4096, 4096, 256, 1,
-                                      jnp.float32, interpret=False)
-    assert not fused_loop.block_plan_fits(16384, 16384, 1024, 1,
-                                          jnp.float32, interpret=False)
-    assert fused_loop.block_plan_fits(16384, 16384, 1024, 1,
-                                      jnp.float32, interpret=True)
+    tpu = resolve_backend("tpu-mosaic")
+    interp = resolve_backend("interpret")
+    assert fused_loop.block_plan_fits(2048, 2048, 256, 1, jnp.float32,
+                                      backend=tpu)
+    assert not fused_loop.block_plan_fits(4096, 4096, 256, 1, jnp.float32,
+                                          backend=tpu)
+    assert fused_loop.block_plan_fits(4096, 4096, 256, 1, jnp.float32,
+                                      backend=interp)
     # bf16 halves the factor bytes — shapes near the boundary fit again
     assert fused_loop.block_vmem_bytes(8192, 8192, 128, 1, jnp.bfloat16) \
         < fused_loop.block_vmem_bytes(8192, 8192, 128, 1, jnp.float32)
+    # the (n, B) carries are lane-padded: B = 1 costs what B = 128 does
+    assert fused_loop.block_vmem_bytes(1024, 1024, 256, 1) \
+        == fused_loop.block_vmem_bytes(1024, 1024, 256, 128)
 
 
 def test_misaligned_cadence_raises():

@@ -108,6 +108,23 @@ def test_operators_match_dense_kernel(family, size):
                                np.asarray(K.T @ u), rtol=3e-4, atol=1e-6)
 
 
+@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("precision", ["highest", "bf16"])
+def test_operators_multiply_in_f32_under_vmap(family, precision):
+    """Every contraction of the scaling operators asks for f32 products.
+    At its default precision the TPU's MXU rounds f32 operands to bf16;
+    the vmapped matvecs of ``solve_many`` and the service then stalled
+    far above a 1e-6 marginal tolerance on the chip."""
+    geom = _make_geometry(family, 40, 36)
+    n, m = geom.shape
+    for op, size in zip(geom.operators(precision=precision), (m, n)):
+        text = str(jax.make_jaxpr(jax.vmap(op))(jnp.ones((3, size))))
+        dots = text.count("dot_general")
+        assert dots >= 1
+        assert text.count("precision=(Precision.HIGHEST, "
+                          "Precision.HIGHEST)") == dots
+
+
 @pytest.mark.parametrize("family",
                          [f for f in FAMILIES if f != "nystrom"])
 @pytest.mark.parametrize("size", SIZES, ids=lambda s: f"n{s[0]}m{s[1]}")

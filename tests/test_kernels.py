@@ -17,6 +17,9 @@ from repro.kernels import (
     sinkhorn_halfstep,
 )
 from repro.kernels import ref
+from repro.kernels.backend import resolve_backend
+from repro.kernels.fused_loop import sinkhorn_block_pallas
+from repro.kernels.paged import paged_halfstep_pallas
 from repro.kernels.tiling import pad_axis, pick_block
 
 
@@ -269,3 +272,33 @@ def test_fused_batched_iteration_matches_reference():
                                    rtol=1e-4)
         np.testing.assert_allclose(np.asarray(v[i]), np.asarray(v_r),
                                    rtol=1e-4)
+
+
+_W, _V, _T = jnp.ones((128, 16)), jnp.ones((128, 1)), jnp.ones((16, 1))
+_CONTRACTING_KERNELS = {
+    "feature_contract": lambda: feature_contract(_W, _V, backend="interpret"),
+    "feature_matvec": lambda: feature_matvec(_W, _T, backend="interpret"),
+    "sinkhorn_halfstep": lambda: sinkhorn_halfstep(_W, _T, _V,
+                                                   backend="interpret"),
+    "gaussian_feature_map": lambda: gaussian_feature_map(
+        jnp.ones((128, 2)), jnp.ones((16, 2)), jnp.zeros((16,)),
+        inv_eps=1.0, backend="interpret"),
+    "megakernel": lambda: sinkhorn_block_pallas(
+        _W, _W, _V, _V, _V, _V, _V, inner_steps=2,
+        backend=resolve_backend("interpret")),
+    "paged_halfstep": lambda: paged_halfstep_pallas(
+        _W, _T, _V, jnp.ones((2,), jnp.int32), page_size=64,
+        backend="interpret"),
+}
+
+
+@pytest.mark.parametrize("kernel", sorted(_CONTRACTING_KERNELS))
+def test_kernel_contractions_multiply_in_f32(kernel):
+    """Every dot in a kernel body asks for f32 products: at the default
+    precision Mosaic rounds f32 operands to bf16 (3e-3 relative on a
+    v5e), which moved the megakernel's converged costs by 3e-5."""
+    text = str(jax.make_jaxpr(_CONTRACTING_KERNELS[kernel])())
+    dots = text.count("dot_general")
+    assert dots >= 1
+    assert text.count("precision=(Precision.HIGHEST, "
+                      "Precision.HIGHEST)") == dots

@@ -127,3 +127,24 @@ ENTRY %main (a: f32[8]) -> f32[8] {
     per = 2 * 8 * 4 * (3 / 4)
     np.testing.assert_allclose(res["wire_bytes_per_device"], 3 * per)
     assert res["collective_counts"]["all-reduce"] == 3
+
+
+def test_compile_cache_dir_from_env_or_checkout(monkeypatch, tmp_path):
+    """The cache goes where ``JAX_COMPILATION_CACHE_DIR`` says, and no
+    directory is set in code then; otherwise it is one fixed path at the
+    root of the checkout."""
+    from repro.launch import compile_cache
+
+    was = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv(compile_cache.CACHE_ENV, str(tmp_path))
+        assert compile_cache.enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == was
+        monkeypatch.delenv(compile_cache.CACHE_ENV)
+        path = compile_cache.enable_compile_cache()
+        assert path == jax.config.jax_compilation_cache_dir
+        assert path == str(compile_cache.DEFAULT_CACHE_DIR)
+        assert (compile_cache.DEFAULT_CACHE_DIR.parent
+                / "chip_smoke.py").is_file()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", was)

@@ -5,7 +5,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.core.routing import sinkhorn_route
-from repro.distributed.sharding import shard_map
 from repro.models.moe import init_moe, moe_dense, moe_ep_local, router_probs
 
 
@@ -23,7 +22,7 @@ def test_ep_matches_dense_single_rank():
     out_d, aux_d = moe_dense(p, x, top_k=2)
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
                 ("data", "model"))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p_, x_: moe_ep_local(p_, x_, top_k=2, n_experts=8,
                                     axis="model", capacity_factor=8.0),
         mesh=mesh,
@@ -45,7 +44,7 @@ def test_ep_gradients_flow():
                 ("data", "model"))
 
     def loss(p_, x_):
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda pp, xx: moe_ep_local(pp, xx, top_k=2, n_experts=8,
                                         axis="model", capacity_factor=8.0),
             mesh=mesh,
@@ -79,7 +78,7 @@ def test_capacity_drops_bounded():
     p["router"] = jnp.zeros_like(p["router"]).at[:, 0].set(5.0)
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1),
                 ("data", "model"))
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p_, x_: moe_ep_local(p_, x_, top_k=1, n_experts=8,
                                     axis="model", capacity_factor=0.25),
         mesh=mesh,

@@ -305,6 +305,42 @@ def test_service_bad_lane_isolated_and_recovered():
     assert s["health"].get("diverged", 0) >= 1
 
 
+@pytest.mark.parametrize("where", ["batched", "ladder"])
+def test_service_runner_build_errors_propagate(where, monkeypatch):
+    """Recovery absorbs faults of a compiled runner, never an error from
+    building one: a kernel the compiler refuses must fail the request
+    loudly, not come back as a rescued solve on a degraded rung."""
+    from repro.serving.runner_cache import BucketRunner
+
+    svc = OTService(eps=EPS, method="factored", tol=1e-4, max_iter=40,
+                    max_batch=2, max_wait=0.0, recovery=RecoveryPolicy())
+    if where == "ladder":
+        svc.warmup([(14, 12, 8)], batches=[1])  # only rung runners build
+    warmup = BucketRunner.warmup
+
+    def refuse(self):
+        raise RuntimeError("Mosaic failed to compile the kernel")
+
+    monkeypatch.setattr(BucketRunner, "warmup", refuse)
+    if where == "ladder":
+        # a runtime fault in the batched run sends the request down the
+        # ladder, whose rung runners are built on demand
+        monkeypatch.setattr(svc, "chaos_hook", ChaosInjector(ChaosSpec(
+            runner_fault_frac=1.0, nan_feature_frac=0.0,
+            inf_feature_frac=0.0, nan_weight_frac=0.0)).fault_hook())
+    svc.submit(_problem(14, 12, seed=3))
+    with pytest.raises(RuntimeError, match="failed to compile"):
+        svc.drain()
+    assert svc.runner_faults == (1 if where == "ladder" else 0)
+    assert svc.recovered == 0
+    # with runner builds restored, a runtime fault is still absorbed
+    monkeypatch.setattr(BucketRunner, "warmup", warmup)
+    if where == "ladder":
+        t = svc.submit(_problem(14, 12, seed=3))
+        svc.drain()
+        assert t.done and svc.runner_faults == 2 and svc.recovered == 1
+
+
 # -- warm-start cache hygiene (satellite: cache poisoning) --------------------
 
 

@@ -189,7 +189,6 @@ def test_rot_geometry_envelope_vjp_under_shard_map():
         from jax.sharding import PartitionSpec as P
         from repro.core import rot_geometry
         from repro.core.sharded import RowShardedFactored
-        from repro.distributed.sharding import shard_map
         eps, n, m, r = 0.1, 48, 40, 32
         a, b = uniform(n, m)
         lxi = jnp.log(jax.random.uniform(key, (n, r)) + 0.05)
@@ -206,7 +205,7 @@ def test_rot_geometry_envelope_vjp_under_shard_map():
                 g = RowShardedFactored(log_xi=lx_, log_zeta=lz_, eps=eps,
                                        axis="data")
                 return rot_geometry(g, a_, b_, 1e-6, 2000)
-            fn = shard_map(
+            fn = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(P("data", None), P("data", None),
                           P("data"), P("data")),
