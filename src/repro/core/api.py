@@ -62,6 +62,7 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..configs.shapes import OTBatchShape
 from .accelerated import accelerated_sinkhorn_geometry
 from .geometry import (
@@ -702,52 +703,54 @@ def solve(
     ``"highest"`` for small-eps log solves where log-features span
     hundreds of nats.
     """
-    from .spec import SolveSpec  # lazy: spec imports this module
+    call = obs.count("ot.solve.calls")
+    with obs.span("ot.solve", call=call):
+        from .spec import SolveSpec  # lazy: spec imports this module
 
-    if isinstance(problem, SolveSpec):
-        spec = problem
-        if spec.recovery is not None:
-            from ..resilience.ladder import solve_with_recovery
-            return solve_with_recovery(spec).result
-        kw = spec.solver_kwargs()
-        kw.pop("method")
-        kw.pop("schedule")
-        with spec.policy.scope():
-            prob = spec.problem()
-            meth = spec.method
-            if meth == "auto":
-                meth = _auto_method(prob, spec.policy.mesh)
-            if spec.schedule is not None:
-                return solve_annealed(
-                    prob, method=meth, schedule=spec.schedule, **kw
-                ).result
-            return _solve_stage(
-                prob, meth, prob.eps, f_init=None, g_init=None, **kw)
-    if (use_pallas is not None or inner_steps is not None
-            or check_every is not None or precision != "highest"):
-        warnings.warn(
-            "passing execution kwargs (use_pallas=/inner_steps=/"
-            "check_every=/precision=) to solve() directly is deprecated: "
-            "build a SolveSpec with an ExecutionPolicy "
-            "(repro.core.spec) and call solve(spec)",
-            DeprecationWarning, stacklevel=2)
-    if method == "auto":
-        method = _auto_method(problem, mesh)
-    if schedule is not None:
-        return solve_annealed(
-            problem, method=method, schedule=schedule, tol=tol,
-            max_iter=max_iter, momentum=momentum, mesh=mesh,
+        if isinstance(problem, SolveSpec):
+            spec = problem
+            if spec.recovery is not None:
+                from ..resilience.ladder import solve_with_recovery
+                return solve_with_recovery(spec).result
+            kw = spec.solver_kwargs()
+            kw.pop("method")
+            kw.pop("schedule")
+            with spec.policy.scope():
+                prob = spec.problem()
+                meth = spec.method
+                if meth == "auto":
+                    meth = _auto_method(prob, spec.policy.mesh)
+                if spec.schedule is not None:
+                    return solve_annealed(
+                        prob, method=meth, schedule=spec.schedule, **kw
+                    ).result
+                return _solve_stage(
+                    prob, meth, prob.eps, f_init=None, g_init=None, **kw)
+        if (use_pallas is not None or inner_steps is not None
+                or check_every is not None or precision != "highest"):
+            warnings.warn(
+                "passing execution kwargs (use_pallas=/inner_steps=/"
+                "check_every=/precision=) to solve() directly is deprecated: "
+                "build a SolveSpec with an ExecutionPolicy "
+                "(repro.core.spec) and call solve(spec)",
+                DeprecationWarning, stacklevel=2)
+        if method == "auto":
+            method = _auto_method(problem, mesh)
+        if schedule is not None:
+            return solve_annealed(
+                problem, method=method, schedule=schedule, tol=tol,
+                max_iter=max_iter, momentum=momentum, mesh=mesh,
+                mesh_axis=mesh_axis, rank=rank, key=key, use_pallas=use_pallas,
+                inner_steps=inner_steps, check_every=check_every,
+                precision=precision,
+            ).result
+        return _solve_stage(
+            problem, method, problem.eps, tol=tol, max_iter=max_iter,
+            momentum=momentum, f_init=None, g_init=None, mesh=mesh,
             mesh_axis=mesh_axis, rank=rank, key=key, use_pallas=use_pallas,
             inner_steps=inner_steps, check_every=check_every,
             precision=precision,
-        ).result
-    return _solve_stage(
-        problem, method, problem.eps, tol=tol, max_iter=max_iter,
-        momentum=momentum, f_init=None, g_init=None, mesh=mesh,
-        mesh_axis=mesh_axis, rank=rank, key=key, use_pallas=use_pallas,
-        inner_steps=inner_steps, check_every=check_every,
-        precision=precision,
-    )
+        )
 
 
 # ---------------------------------------------------------------------------
@@ -1046,46 +1049,48 @@ class BatchedSinkhorn:
                     )
         groups: Dict[OTBatchShape, List[int]] = {}
         datas: Dict[int, Tuple[jax.Array, jax.Array]] = {}
-        for i, p in enumerate(problems):
-            if float(p.eps) != float(self.eps):
-                raise ValueError(
-                    f"problem {i} declares eps={p.eps} but this engine "
-                    f"solves at eps={self.eps}; build one engine per eps"
-                )
-            ka, kb = self.kernel_data(p)
-            datas[i] = (ka, kb)
-            groups.setdefault(self.batch_shape(ka, kb), []).append(i)
+        with obs.span("ot.stage"):
+            for i, p in enumerate(problems):
+                if float(p.eps) != float(self.eps):
+                    raise ValueError(
+                        f"problem {i} declares eps={p.eps} but this engine "
+                        f"solves at eps={self.eps}; build one engine per eps"
+                    )
+                ka, kb = self.kernel_data(p)
+                datas[i] = (ka, kb)
+                groups.setdefault(self.batch_shape(ka, kb), []).append(i)
 
         out: List[Optional[SinkhornResult]] = [None] * len(problems)
         for shape, idxs in groups.items():
-            kas, kbs, aws, bws, f0s, g0s = [], [], [], [], [], []
-            warm = f_inits is not None and any(
-                f_inits[i] is not None for i in idxs
-            )
-            for i in idxs:
-                p = problems[i]
-                ka, kb = self.pad_kernel_data(*datas[i], shape)
-                kas.append(ka)
-                kbs.append(kb)
-                aws.append(_pad_rows(p.a, shape.n_pad, replicate=False))
-                bws.append(_pad_rows(p.b, shape.m_pad, replicate=False))
-                if warm:
-                    fi = f_inits[i]
-                    gi = g_inits[i]
-                    if fi is None:                 # cold lane: zeros == cold
-                        f0s.append(jnp.zeros((shape.n_pad,), p.a.dtype))
-                        g0s.append(jnp.zeros((shape.m_pad,), p.b.dtype))
-                    else:
-                        f0s.append(_pad_rows(fi, shape.n_pad,
-                                             replicate=False))
-                        g0s.append(_pad_rows(gi, shape.m_pad,
-                                             replicate=False))
-            stacked = (jnp.stack(kas), jnp.stack(kbs),
-                       jnp.stack(aws), jnp.stack(bws))
-            if warm:
-                res = self._vsolve_features_warm(
-                    *stacked, jnp.stack(f0s), jnp.stack(g0s)
+            with obs.span("ot.stage"):
+                kas, kbs, aws, bws, f0s, g0s = [], [], [], [], [], []
+                warm = f_inits is not None and any(
+                    f_inits[i] is not None for i in idxs
                 )
+                for i in idxs:
+                    p = problems[i]
+                    ka, kb = self.pad_kernel_data(*datas[i], shape)
+                    kas.append(ka)
+                    kbs.append(kb)
+                    aws.append(_pad_rows(p.a, shape.n_pad, replicate=False))
+                    bws.append(_pad_rows(p.b, shape.m_pad, replicate=False))
+                    if warm:
+                        fi = f_inits[i]
+                        gi = g_inits[i]
+                        if fi is None:             # cold lane: zeros == cold
+                            f0s.append(jnp.zeros((shape.n_pad,), p.a.dtype))
+                            g0s.append(jnp.zeros((shape.m_pad,), p.b.dtype))
+                        else:
+                            f0s.append(_pad_rows(fi, shape.n_pad,
+                                                 replicate=False))
+                            g0s.append(_pad_rows(gi, shape.m_pad,
+                                                 replicate=False))
+                stacked = (jnp.stack(kas), jnp.stack(kbs),
+                           jnp.stack(aws), jnp.stack(bws))
+                if warm:
+                    stacked += (jnp.stack(f0s), jnp.stack(g0s))
+            if warm:
+                res = self._vsolve_features_warm(*stacked)
             else:
                 res = self._vsolve_features(*stacked)
             for j, i in enumerate(idxs):
@@ -1271,120 +1276,121 @@ def solve_many(
     heterogeneous configs go through ``solve(spec)`` one at a time); the
     solver kwargs above are then ignored except ``f_inits``/``g_inits``.
     """
-    if not problems:
-        return []
-    from .spec import SolveSpec  # lazy: spec imports this module
+    with obs.span("ot.solve_many"):
+        if not problems:
+            return []
+        from .spec import SolveSpec  # lazy: spec imports this module
 
-    if isinstance(problems[0], SolveSpec):
-        specs: List[SolveSpec] = list(problems)
-        head = specs[0]
-        shared = (head.method, head.tol, head.max_iter, head.momentum,
-                  head.policy, head.recovery)
-        for s in specs:
-            if not isinstance(s, SolveSpec):
-                raise TypeError(
-                    "solve_many: mixed SolveSpec and OTProblem entries")
-            if (s.method, s.tol, s.max_iter, s.momentum,
-                    s.policy, s.recovery) != shared:
+        if isinstance(problems[0], SolveSpec):
+            specs: List[SolveSpec] = list(problems)
+            head = specs[0]
+            shared = (head.method, head.tol, head.max_iter, head.momentum,
+                      head.policy, head.recovery)
+            for s in specs:
+                if not isinstance(s, SolveSpec):
+                    raise TypeError(
+                        "solve_many: mixed SolveSpec and OTProblem entries")
+                if (s.method, s.tol, s.max_iter, s.momentum,
+                        s.policy, s.recovery) != shared:
+                    raise ValueError(
+                        "solve_many(specs) needs one shared method/tol/"
+                        "max_iter/momentum/policy/recovery across specs "
+                        "(engines are per-configuration); call solve(spec) "
+                        "per problem for heterogeneous configs")
+                if s.schedule is not None or s.rank is not None \
+                        or s.key is not None:
+                    raise ValueError(
+                        "solve_many(specs) does not support schedule/rank/"
+                        "key; call solve(spec) per problem")
+            pol = head.policy
+            if pol.mesh is not None:
+                if f_inits is not None or g_inits is not None:
+                    raise ValueError(
+                        "sharded solve_many dispatches sequentially; "
+                        "per-problem warm starts are a batched-engine "
+                        "feature — drop the mesh or the inits")
+                twin = _SHARDED_TWIN.get(head.method)
+                if twin is None:
+                    raise ValueError(
+                        f"solve_many(mesh=...) supports methods "
+                        f"{sorted(_SHARDED_TWIN)}, got {head.method!r}")
+                return [solve(s.replace(method=twin)) for s in specs]
+            eps_set = {float(s.eps) for s in specs}
+            if len(eps_set) != 1:
                 raise ValueError(
-                    "solve_many(specs) needs one shared method/tol/"
-                    "max_iter/momentum/policy/recovery across specs "
-                    "(engines are per-configuration); call solve(spec) "
-                    "per problem for heterogeneous configs")
-            if s.schedule is not None or s.rank is not None \
-                    or s.key is not None:
-                raise ValueError(
-                    "solve_many(specs) does not support schedule/rank/"
-                    "key; call solve(spec) per problem")
-        pol = head.policy
-        if pol.mesh is not None:
+                    f"mixed spec eps {sorted(eps_set)}; batched engines "
+                    "are per-eps — group specs by eps")
+            eng_method = ("log_factored" if head.method == "auto"
+                          else head.method)
+            with pol.scope():
+                engine = get_engine(
+                    eps=eps_set.pop(), method=eng_method, tol=head.tol,
+                    max_iter=head.max_iter, momentum=head.momentum,
+                    use_pallas=pol.use_pallas, inner_steps=pol.inner_steps,
+                    check_every=pol.check_every, precision=pol.precision,
+                )
+                results = engine.solve_many([s.problem() for s in specs],
+                                            f_inits=f_inits, g_inits=g_inits)
+            if head.recovery is not None:
+                # failed lanes climb the ladder INDIVIDUALLY (batched lanes
+                # are independent under vmap — a diverged lane never poisons
+                # its siblings, so only the failures pay for retries); the
+                # already-computed lane result seeds the ladder so the base
+                # configuration is not re-failed
+                from ..resilience.health import classify
+                from ..resilience.ladder import solve_with_recovery
+                for i, r in enumerate(results):
+                    fi = f_inits[i] if f_inits is not None else None
+                    gi = g_inits[i] if g_inits is not None else None
+                    h = classify(r, f_init=fi, g_init=gi,
+                                 a=specs[i].problem().a, b=specs[i].problem().b)
+                    if h.verdict not in head.recovery.accept:
+                        results[i] = solve_with_recovery(
+                            specs[i], first_attempt=r).result
+            return results
+        if (use_pallas is not None or inner_steps is not None
+                or check_every is not None or precision != "highest"):
+            warnings.warn(
+                "passing execution kwargs (use_pallas=/inner_steps=/"
+                "check_every=/precision=) to solve_many() directly is "
+                "deprecated: build SolveSpecs with a shared ExecutionPolicy "
+                "(repro.core.spec) and call solve_many(specs)",
+                DeprecationWarning, stacklevel=2)
+        eps_set = {float(p.eps) for p in problems}
+        if eps is None:
+            if len(eps_set) != 1:
+                raise ValueError(f"mixed problem eps {sorted(eps_set)}; pass eps=")
+            eps = eps_set.pop()
+        if mesh is not None:
             if f_inits is not None or g_inits is not None:
                 raise ValueError(
-                    "sharded solve_many dispatches sequentially; "
-                    "per-problem warm starts are a batched-engine "
-                    "feature — drop the mesh or the inits")
-            twin = _SHARDED_TWIN.get(head.method)
+                    "solve_many(mesh=...) dispatches problems sequentially "
+                    "through solve(); per-problem warm starts are a batched-"
+                    "engine feature — drop mesh= or the inits"
+                )
+            twin = _SHARDED_TWIN.get(method)
             if twin is None:
                 raise ValueError(
                     f"solve_many(mesh=...) supports methods "
-                    f"{sorted(_SHARDED_TWIN)}, got {head.method!r}")
-            return [solve(s.replace(method=twin)) for s in specs]
-        eps_set = {float(s.eps) for s in specs}
-        if len(eps_set) != 1:
-            raise ValueError(
-                f"mixed spec eps {sorted(eps_set)}; batched engines "
-                "are per-eps — group specs by eps")
-        eng_method = ("log_factored" if head.method == "auto"
-                      else head.method)
-        with pol.scope():
-            engine = get_engine(
-                eps=eps_set.pop(), method=eng_method, tol=head.tol,
-                max_iter=head.max_iter, momentum=head.momentum,
-                use_pallas=pol.use_pallas, inner_steps=pol.inner_steps,
-                check_every=pol.check_every, precision=pol.precision,
-            )
-            results = engine.solve_many([s.problem() for s in specs],
-                                        f_inits=f_inits, g_inits=g_inits)
-        if head.recovery is not None:
-            # failed lanes climb the ladder INDIVIDUALLY (batched lanes
-            # are independent under vmap — a diverged lane never poisons
-            # its siblings, so only the failures pay for retries); the
-            # already-computed lane result seeds the ladder so the base
-            # configuration is not re-failed
-            from ..resilience.health import classify
-            from ..resilience.ladder import solve_with_recovery
-            for i, r in enumerate(results):
-                fi = f_inits[i] if f_inits is not None else None
-                gi = g_inits[i] if g_inits is not None else None
-                h = classify(r, f_init=fi, g_init=gi,
-                             a=specs[i].problem().a, b=specs[i].problem().b)
-                if h.verdict not in head.recovery.accept:
-                    results[i] = solve_with_recovery(
-                        specs[i], first_attempt=r).result
-        return results
-    if (use_pallas is not None or inner_steps is not None
-            or check_every is not None or precision != "highest"):
-        warnings.warn(
-            "passing execution kwargs (use_pallas=/inner_steps=/"
-            "check_every=/precision=) to solve_many() directly is "
-            "deprecated: build SolveSpecs with a shared ExecutionPolicy "
-            "(repro.core.spec) and call solve_many(specs)",
-            DeprecationWarning, stacklevel=2)
-    eps_set = {float(p.eps) for p in problems}
-    if eps is None:
-        if len(eps_set) != 1:
-            raise ValueError(f"mixed problem eps {sorted(eps_set)}; pass eps=")
-        eps = eps_set.pop()
-    if mesh is not None:
-        if f_inits is not None or g_inits is not None:
-            raise ValueError(
-                "solve_many(mesh=...) dispatches problems sequentially "
-                "through solve(); per-problem warm starts are a batched-"
-                "engine feature — drop mesh= or the inits"
-            )
-        twin = _SHARDED_TWIN.get(method)
-        if twin is None:
-            raise ValueError(
-                f"solve_many(mesh=...) supports methods "
-                f"{sorted(_SHARDED_TWIN)}, got {method!r}"
-            )
-        # use_pallas is moot here: sharded geometries refuse fused local
-        # plans (they would drop the psum), so the XLA operators always
-        # run. inner_steps is NOT moot — it is passed through so the
-        # sharded runner raises its clear megakernel-refusal error
-        # instead of silently dropping the knob; check_every/precision
-        # apply as everywhere.
-        return [
-            solve(p.__class__(p.geometry.rebuild_at(eps), p.a, p.b),
-                  method=twin, tol=tol, max_iter=max_iter,
-                  momentum=momentum, mesh=mesh, mesh_axis=mesh_axis,
-                  inner_steps=inner_steps, check_every=check_every,
-                  precision=precision)
-            for p in problems
-        ]
-    engine = get_engine(
-        eps=eps, method=method, tol=tol, max_iter=max_iter,
-        momentum=momentum, use_pallas=use_pallas, inner_steps=inner_steps,
-        check_every=check_every, precision=precision,
-    )
-    return engine.solve_many(problems, f_inits=f_inits, g_inits=g_inits)
+                    f"{sorted(_SHARDED_TWIN)}, got {method!r}"
+                )
+            # use_pallas is moot here: sharded geometries refuse fused local
+            # plans (they would drop the psum), so the XLA operators always
+            # run. inner_steps is NOT moot — it is passed through so the
+            # sharded runner raises its clear megakernel-refusal error
+            # instead of silently dropping the knob; check_every/precision
+            # apply as everywhere.
+            return [
+                solve(p.__class__(p.geometry.rebuild_at(eps), p.a, p.b),
+                      method=twin, tol=tol, max_iter=max_iter,
+                      momentum=momentum, mesh=mesh, mesh_axis=mesh_axis,
+                      inner_steps=inner_steps, check_every=check_every,
+                      precision=precision)
+                for p in problems
+            ]
+        engine = get_engine(
+            eps=eps, method=method, tol=tol, max_iter=max_iter,
+            momentum=momentum, use_pallas=use_pallas, inner_steps=inner_steps,
+            check_every=check_every, precision=precision,
+        )
+        return engine.solve_many(problems, f_inits=f_inits, g_inits=g_inits)

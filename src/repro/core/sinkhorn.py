@@ -55,11 +55,11 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from .. import obs
 from ..kernels.backend import resolve_backend
 from ..kernels.ops import (
     check_precision,
     geometry_ops,
-    notify_plan_selected,
     relax_log,
     relax_scaling,
 )
@@ -270,6 +270,12 @@ def run_marginal_loop(step, carry0, *, tol: float, max_iter: int, dtype,
     ``shard_map`` the step's ``err_reduce`` (see :func:`geometry_reduce`)
     psums the error, so the while_loop carries a REPLICATED scalar and
     every device exits at the same iteration (no control-flow divergence).
+
+    Observability: the ``ot.loop`` span covers the eager first block and
+    the while_loop's trace, lowering, compile (or cache read) and dispatch;
+    ``cond`` counts ``ot.loop.traces`` as it is traced (``body`` also runs
+    once eagerly, ``cond`` does not), so a solve that re-traces its loop
+    adds one a call and a cached one adds none.
     """
     cadence = steps_per_check * iters_per_step
 
@@ -280,11 +286,14 @@ def run_marginal_loop(step, carry0, *, tol: float, max_iter: int, dtype,
         return it + cadence, carry, err
 
     def cond(state):
+        obs.count("ot.loop.traces")
         it, _, err = state
         return (it < max_iter) & (err > tol) & jnp.isfinite(err)
 
-    state0 = body((jnp.array(0, jnp.int32), carry0, jnp.asarray(jnp.inf, dtype)))
-    return jax.lax.while_loop(cond, body, state0)
+    with obs.span("ot.loop"):
+        state0 = body((jnp.array(0, jnp.int32), carry0,
+                       jnp.asarray(jnp.inf, dtype)))
+        return jax.lax.while_loop(cond, body, state0)
 
 
 # ---------------------------------------------------------------------------
@@ -303,7 +312,7 @@ def _maybe_pallas_plan(geom: Geometry, use_pallas: Optional[bool],
     the test configuration), ``False`` forces the XLA operators.
     Geometries without a fused plan (dense, Nystrom, grids) always fall
     back. :func:`_plan_loop` reports the selection through the
-    ``kernels.ops.observe_plan_selection`` hook.
+    ``repro.obs.observe_plan_selection`` hook.
     """
     if geom.spmd_axis is not None:
         # a fused local plan would drop the psum — sharded geometries
@@ -365,7 +374,7 @@ def _plan_loop(plan, step_args, *, geometry, tol, max_iter, dtype,
     if inner > 1 and plan.make_block_step is not None:
         block = plan.make_block_step(a, b, inner_steps=inner,
                                      momentum=momentum)
-    notify_plan_selected({
+    obs.notify_plan_selected({
         "geometry": geometry,
         "mode": plan.mode,
         "kind": plan.kind,
@@ -392,9 +401,10 @@ def _plan_loop(plan, step_args, *, geometry, tol, max_iter, dtype,
 
 def _finish_scaling(a, b, u, v, it, err, *, eps, tol,
                     reduce: Callable = jnp.sum) -> SinkhornResult:
-    f, g = eps * _masked_log(u), eps * _masked_log(v)
-    cost = masked_dual_value(a, b, f, g, reduce)
-    return SinkhornResult(u, v, f, g, cost, it, err, err <= tol)
+    with obs.span("ot.finish"):
+        f, g = eps * _masked_log(u), eps * _masked_log(v)
+        cost = masked_dual_value(a, b, f, g, reduce)
+        return SinkhornResult(u, v, f, g, cost, it, err, err <= tol)
 
 
 def _solve_scaling_plan(plan, a, b, *, geometry, eps, tol, max_iter,
@@ -504,15 +514,17 @@ def sinkhorn_geometry(
     mixed-precision execution policy).
     """
     check_precision(precision)
-    plan = _maybe_pallas_plan(geom, use_pallas, "scaling", precision)
+    with obs.span("ot.featurize"):
+        plan = _maybe_pallas_plan(geom, use_pallas, "scaling", precision)
+        if plan is None:
+            _, check, _ = _resolve_cadence(None, inner_steps, check_every)
+            matvec, rmatvec = geom.operators(precision=precision)
     if plan is not None:
         return _solve_scaling_plan(
             plan, a, b, geometry=type(geom).__name__, eps=geom.eps,
             tol=tol, max_iter=max_iter, momentum=momentum, u_init=u_init,
             inner_steps=inner_steps, check_every=check_every,
         )
-    _, check, _ = _resolve_cadence(None, inner_steps, check_every)
-    matvec, rmatvec = geom.operators(precision=precision)
     return sinkhorn_operator(
         matvec, rmatvec, a, b, eps=geom.eps, tol=tol,
         max_iter=max_iter, momentum=momentum, u_init=u_init,
@@ -595,7 +607,11 @@ def sinkhorn_log_geometry(
     and bf16 log-feature storage with f32 LSE accumulation.
     """
     check_precision(precision)
-    plan = _maybe_pallas_plan(geom, use_pallas, "log", precision)
+    with obs.span("ot.featurize"):
+        plan = _maybe_pallas_plan(geom, use_pallas, "log", precision)
+        if plan is None:
+            _, check, _ = _resolve_cadence(None, inner_steps, check_every)
+            log_matvec, log_rmatvec = geom.log_operators(precision=precision)
     if plan is not None:
         return _solve_log_plan(
             plan, a, b, geometry=type(geom).__name__, eps=geom.eps,
@@ -603,8 +619,6 @@ def sinkhorn_log_geometry(
             momentum=momentum, f_init=f_init, g_init=g_init,
             inner_steps=inner_steps, check_every=check_every,
         )
-    _, check, _ = _resolve_cadence(None, inner_steps, check_every)
-    log_matvec, log_rmatvec = geom.log_operators(precision=precision)
     return _log_domain_solve(
         log_matvec, log_rmatvec, a, b, eps=geom.eps, tol=tol,
         max_iter=max_iter, momentum=momentum, f_init=f_init, g_init=g_init,
@@ -634,9 +648,10 @@ def _log_init(a, b, f_init, g_init):
 
 def _finish_log(a, b, f, g, it, err, *, eps, tol,
                 reduce: Callable = jnp.sum) -> SinkhornResult:
-    cost = masked_dual_value(a, b, f, g, reduce)
-    u, v = jnp.exp(f / eps), jnp.exp(g / eps)
-    return SinkhornResult(u, v, f, g, cost, it, err, err <= tol)
+    with obs.span("ot.finish"):
+        cost = masked_dual_value(a, b, f, g, reduce)
+        u, v = jnp.exp(f / eps), jnp.exp(g / eps)
+        return SinkhornResult(u, v, f, g, cost, it, err, err <= tol)
 
 
 def _log_domain_solve(

@@ -24,19 +24,21 @@ step is drop-in compatible with ``core.sinkhorn.run_marginal_loop`` — that
 is how ``sinkhorn_geometry`` / ``sinkhorn_log_geometry`` route their
 ``lax.while_loop`` hot loop through the fused kernels (``use_pallas``).
 
-``observe_plan_selection`` is the test hook: while the context is active,
-every fused-plan selection on a solve path appends an event dict, so tests
-can assert the hot loop really ran through the plan.
+``observe_plan_selection`` is the test hook (``repro.obs``, re-exported
+here): while the context is active, every fused-plan selection on a solve
+path appends an event dict, so tests can assert the hot loop really ran
+through the plan.
 """
 from __future__ import annotations
 
-import contextlib
 import functools
-from typing import Callable, List, NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
+from ..obs import _PLAN_OBSERVERS  # noqa: F401  (the plan hook lives there)
+from ..obs import notify_plan_selected, observe_plan_selection
 from .backend import Backend, fused_map_admissible, resolve_backend
 from .feature_map import gaussian_feature_map_pallas
 from .fused_loop import (
@@ -666,34 +668,3 @@ def geometry_ops(geom, *,
             return _scaling_plan(kind, xi, zeta, be, precision)
         return _log_plan(kind, xi, zeta, float(geom.eps), be, precision)
     raise ValueError(f"unknown pallas_ops spec kind {kind!r}")
-
-
-# ---------------------------------------------------------------------------
-# Plan-selection hook (test observability)
-# ---------------------------------------------------------------------------
-
-_PLAN_OBSERVERS: List[Callable[[dict], None]] = []
-
-
-def notify_plan_selected(event: dict) -> None:
-    """Called by the solvers when a fused plan is installed on a hot loop.
-
-    Fires at TRACE time (plan selection is a Python-level decision), so a
-    jitted solve notifies on its first call per compilation."""
-    for cb in list(_PLAN_OBSERVERS):
-        cb(dict(event))
-
-
-@contextlib.contextmanager
-def observe_plan_selection():
-    """Collect plan-selection events: ``with observe_plan_selection() as ev:
-    solve(...)`` then assert on ``ev`` (list of dicts with ``geometry`` /
-    ``mode`` / ``kind`` / ``precision`` / ``interpret`` keys, and ``step``:
-    "megakernel" for the persistent block step, "per_iteration" for the
-    streaming plan)."""
-    events: List[dict] = []
-    _PLAN_OBSERVERS.append(events.append)
-    try:
-        yield events
-    finally:
-        _PLAN_OBSERVERS.remove(events.append)
