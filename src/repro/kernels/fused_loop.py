@@ -18,11 +18,13 @@ This module collapses ``inner_steps`` FULL iterations into ONE
     only scalar that leaves the chip per block.
 
 Numerics are the per-iteration plan's, step for step: the same
-``s = Zeta^T (Xi^T u)`` carry reuse, the same momentum relaxations, the
-same exact joint-max LSE stabilization in log mode — so a block of
-``inner_steps`` megakernel iterations matches ``inner_steps`` unfused plan
-steps elementwise at the block boundary (single-tile shapes; multi-tile
-shapes differ only by f32 summation order).
+``s = Zeta^T (Xi^T u)`` carry reuse in scaling mode (log mode carries the
+stage-1 LSE, the per-iteration plan the column log-marginal it feeds), the
+same momentum relaxations, the same exact joint-max LSE stabilization in
+log mode — so a block of ``inner_steps`` megakernel iterations matches
+``inner_steps`` unfused plan steps elementwise at the block boundary
+(single-tile shapes; multi-tile shapes differ only by f32 summation
+order).
 
 Mixed precision: feature operands may arrive in bf16 (the
 ``precision="bf16"`` execution policy — half the HBM stream). Kernels
@@ -315,12 +317,16 @@ def _log_block_kernel(lxi_ref, lzt_ref, loga_ref, logb_ref, b_ref,
                       n_cols: int):
     """``inner_steps`` full log-domain iterations on-chip.
 
-    Step semantics identical to ``ops._log_plan``: carry (f, g, t1) with
-    t1 = LSE_i(logXi + f/eps) reused by both the next g-update and the
-    block-boundary marginal check. The B columns are UNROLLED at trace
-    time with the exact per-column joint max (the ``logmatvec``
-    stabilization contract), so B stays unpadded — B = 1 on the solver
-    path, batching rides the vmap grid axis.
+    The carry is (f, g, t1) with t1 = LSE_i(logXi + f/eps): each
+    iteration takes the g-update's second LSE stage from the VMEM-resident
+    factors, and the marginal check runs at the block boundary only. The
+    per-iteration step (``ops._log_plan``) carries that second stage
+    instead, ``log(K^T e^{f/eps}) = _lse_rows(lzt, t1)``, so the two
+    carries differ in their third element; ``(f, g)`` and the error match
+    at block boundaries. The B columns are UNROLLED at trace time with the
+    exact per-column joint max (the ``logmatvec`` stabilization contract),
+    so B stays unpadded — B = 1 on the solver path, batching rides the
+    vmap grid axis.
     """
     lxi = _f32(lxi_ref[...])        # (n, r) log-features, VMEM-resident
     lzt = _f32(lzt_ref[...])        # (m, r)
@@ -368,7 +374,7 @@ def log_sinkhorn_block_pallas(
 ) -> Tuple[jax.Array, jax.Array, jax.Array, jax.Array]:
     """One megakernel block: ``inner_steps`` log-domain iterations.
 
-    Returns ``(f, g, t, err)`` — the log plan-step carry after the block
+    Returns ``(f, g, t, err)`` — the megakernel's carry after the block
     plus the block-boundary marginal error. Padding: support rows
     replicate the last log-feature row while their weights/potentials pad
     ``-inf`` (the LSE identity) and the linear ``b`` pads 0 — exact

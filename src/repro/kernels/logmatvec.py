@@ -13,7 +13,8 @@ where scalings under/overflow f32):
     half-step fused (the log-space twin of ``sinkhorn_halfstep_pallas``):
         out[j, c] = scale * ( lmarg[j, c] - logsumexp_k(log_w[j,k]+t[k,c]) )
     ``scale=eps`` yields the potential update  g = eps (log b - log K^T u);
-    ``scale=-1, lmarg=0`` yields the raw LSE (convergence check).
+    ``scale=-1, lmarg=0`` yields the raw LSE (the log plan's carried
+    column log-marginal: convergence check and next g-update).
 
 Stabilization in the B-column kernels is EXACT: the B loop is unrolled at
 trace time (B is static) and each column takes a 2-D ``log_w + s[:, c]``

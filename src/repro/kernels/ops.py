@@ -349,10 +349,12 @@ class GeometryOps(NamedTuple):
                     the geometry's XLA operators (same iterates, same
                     marginal error, same masking) — the solver hot loop.
                     ``init`` lifts the primal/dual start values into the
-                    loop carry, which tacks on the reusable intermediate
-                    (``s = K^T u`` in scaling mode, the stage-1 LSE
-                    ``t = LSE(logXi + f/eps)`` in log mode) so the
-                    convergence check costs nothing extra per iteration.
+                    loop carry, which tacks on the column marginal of the
+                    current iterate (``s = K^T u`` in scaling mode, its
+                    log ``log(K^T e^{f/eps})`` (m,) in log mode): the
+                    convergence check computes it and the next
+                    iteration's column update reuses it, so checking
+                    costs no factor pass of its own.
     ``apply_kt``  — scaling mode only: ``u (n,) -> K^T u (m,)`` for the
                     loop-carry initialization.
     ``eps``       — log mode only: the regularization the potentials live
@@ -362,10 +364,13 @@ class GeometryOps(NamedTuple):
                     plan. ``step`` advances ``inner_steps`` full
                     iterations in ONE ``pallas_call`` (``fused_loop``) —
                     factors VMEM-resident, carries on-chip, marginal error
-                    emitted at the block boundary only — over the SAME
-                    carry as ``make_step`` (so the two are
-                    interchangeable in ``run_marginal_loop`` and match
-                    elementwise at block boundaries). Returns ``None``
+                    emitted at the block boundary only. In scaling mode
+                    its carry is ``make_step``'s; in log mode it carries
+                    the stage-1 LSE ``t = LSE(logXi + f/eps)`` (r, 1) in
+                    place of the column log-marginal, so a solve runs one
+                    step or the other, never both. Either way ``(f, g)``
+                    (``(u, v)``) and the error match ``make_step``'s
+                    elementwise at block boundaries. Returns ``None``
                     when the working set exceeds the VMEM budget
                     (``fused_loop.block_plan_fits``) — callers then fall
                     back to the streaming per-iteration ``make_step``.
@@ -465,36 +470,38 @@ def _log_plan(kind: str, log_xi, log_zeta, eps: float, be: Backend,
         )
 
     def contract_f(f):
-        """Stage-1 LSE over logXi — the carried intermediate: computing it
-        once per iteration serves BOTH the convergence check and the next
-        iteration's g-update (the log twin of carrying ``s = K^T u``)."""
+        """Stage-1 LSE over logXi, ``t = LSE_i(logXi + f/eps)`` (r, 1)."""
         return log_feature_contract(log_xi, f[:, None] / eps, backend=be)
 
     def make_step(a, b, *, momentum: float = 1.0,
                   err_reduce: Callable = jnp.sum):
         loga = _masked_log(a)[:, None]
-        logb = _masked_log(b)[:, None]
-        zero = jnp.zeros_like(logb)
+        logb = _masked_log(b)
+        zero = jnp.zeros((b.shape[0], 1), b.dtype)
+
+        def log_col(f):
+            """Both LSE stages, ``log(K^T e^{f/eps})`` (m,) — the carried
+            column log-marginal: computed once per iteration, it serves
+            BOTH the convergence check and the next iteration's g-update
+            (the log twin of carrying ``s = K^T u``)."""
+            return log_halfstep(log_zeta, contract_f(f), zero, scale=-1.0,
+                                backend=be)[:, 0]
 
         def step(carry):
-            f, g, t1 = carry                     # t1 = LSE(logXi + f/eps)
-            g_new = relax_log(
-                log_halfstep(log_zeta, t1, logb, scale=eps,
-                             backend=be)[:, 0], g, momentum)
+            f, g, lcol = carry                # lcol = log(K^T e^{f/eps})
+            # the half-step kernel's epilogue, scale * (lmarg - lse)
+            g_new = relax_log(eps * (logb - lcol), g, momentum)
             t2 = log_feature_contract(log_zeta, g_new[:, None] / eps,
                                       backend=be)
             f_new = relax_log(
                 log_halfstep(log_xi, t2, loga, scale=eps,
                              backend=be)[:, 0], f, momentum)
-            t3 = contract_f(f_new)
-            lse = log_halfstep(log_zeta, t3, zero, scale=-1.0,
-                               backend=be)[:, 0]
-            log_col = lse + g_new / eps
-            err = err_reduce(jnp.abs(jnp.exp(log_col) - b))
-            return (f_new, g_new, t3), err
+            lcol_new = log_col(f_new)
+            err = err_reduce(jnp.abs(jnp.exp(lcol_new + g_new / eps) - b))
+            return (f_new, g_new, lcol_new), err
 
         def init(f0, g0):
-            return (f0, g0, contract_f(f0))
+            return (f0, g0, log_col(f0))
 
         return step, init
 

@@ -6,6 +6,9 @@ Contracts under test (the ISSUE-5 acceptance bar):
   ``GeometryOps.make_block_step``) matches ``inner_steps`` unfused plan
   steps ELEMENTWISE at block boundaries — factored + gaussian, scaling +
   log, with momentum, warm starts and ot_bucket-style zero-weight padding;
+* the per-iteration log plan streams the factors four times a trip (two
+  LSE contractions, two LSE half-steps) and carries the column
+  log-marginal ``log(K^T e^{f/eps})``, which equals the XLA operator's;
 * the ``inner_steps`` / ``check_every`` cadence invariance matrix: final
   cost/potentials match the ``check_every=1`` solve to <= 1e-6 rel across
   families and modes, and iteration counts are exact multiples of the
@@ -16,6 +19,7 @@ Contracts under test (the ISSUE-5 acceptance bar):
   rejects it too, mis-aligned cadences raise, unknown precisions raise.
 """
 import jax
+import jax.extend as jex
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -28,7 +32,7 @@ from repro.core.geometry import (
 )
 from repro.kernels import fused_loop
 from repro.kernels.backend import resolve_backend
-from repro.kernels.ops import geometry_ops
+from repro.kernels.ops import geometry_ops, log_halfstep
 
 KEY = jax.random.PRNGKey(0)
 
@@ -93,6 +97,14 @@ def test_block_step_matches_unfused(family, mode, momentum):
     for _ in range(inner):
         carry, err = step(carry)
     bcarry, berr = bstep(binit(*z0))
+    if mode == "log":
+        # the per-iteration step carries the column log-marginal, the
+        # megakernel the stage-1 LSE it comes from: finish the block's
+        # second stage and compare what the two carry
+        zero = jnp.zeros((m, 1))
+        lcol = log_halfstep(plan.features[1], bcarry[2], zero, scale=-1.0,
+                            backend="interpret")[:, 0]
+        bcarry = (bcarry[0], bcarry[1], lcol)
     for ref, got in zip(carry, bcarry):
         finite = jnp.isfinite(ref)
         assert bool(jnp.all(finite == jnp.isfinite(got)))
@@ -104,6 +116,85 @@ def test_block_step_matches_unfused(family, mode, momentum):
     # the block-boundary error agrees with the per-iteration error up to
     # f32 reduction-order noise
     np.testing.assert_allclose(float(err), float(berr), rtol=1e-3,
+                               atol=1e-7)
+
+
+def _pallas_calls_by_kernel(fn, *args):
+    """Count the ``pallas_call``s in ``fn``'s jaxpr by the jitted kernel
+    wrapper (``_log_contract_impl``, ...) that encloses each."""
+    counts = {}
+
+    def walk(jaxpr, owner):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                counts[owner] = counts.get(owner, 0) + 1
+            for val in eqn.params.values():
+                sub = val.jaxpr if isinstance(val, jex.core.ClosedJaxpr) \
+                    else val
+                if isinstance(sub, jex.core.Jaxpr):
+                    walk(sub, eqn.params.get("name", owner))
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr, None)
+    return counts
+
+
+@pytest.mark.parametrize("momentum", [1.0, 1.3])
+def test_log_plan_step_streams_factors_four_times(momentum):
+    """One per-iteration log step is two LSE contractions and two LSE
+    half-steps — the convergence check's half-step IS the next step's
+    g-update — and ``init`` is one of each."""
+    geom, a, b = _factored(dead=3)
+    plan = geometry_ops(geom, backend="interpret", mode="log")
+    step, init = plan.make_step(a, b, momentum=momentum)
+    f0 = jnp.where(a > 0, 0.0, -jnp.inf)
+    g0 = jnp.zeros_like(b)
+    assert _pallas_calls_by_kernel(step, init(f0, g0)) == {
+        "_log_contract_impl": 2, "_log_halfstep_impl": 2}
+    assert _pallas_calls_by_kernel(init, f0, g0) == {
+        "_log_contract_impl": 1, "_log_halfstep_impl": 1}
+
+
+@pytest.mark.parametrize("momentum", [1.0, 1.3])
+def test_log_plan_carries_column_log_marginal(momentum):
+    """The carried ``lcol`` is the XLA ``log(K^T e^{f/eps})`` of the
+    carried ``f`` after every step, and a whole solve through the plan
+    matches ``make_log_step``'s XLA solve: dead atoms, momentum."""
+    from repro.core.sinkhorn import sinkhorn_log_geometry
+
+    # log-normal factors: a solve of tens of iterations, where uniform
+    # ones converge in two
+    n, m, r = 96, 80, 17
+    xi = jnp.exp(6.0 * jax.random.normal(KEY, (n, r)))
+    zt = jnp.exp(6.0 * jax.random.normal(jax.random.fold_in(KEY, 1), (m, r)))
+    a = jnp.full((n,), 1.0 / n).at[-3:].set(0.0)
+    a = a / a.sum()
+    b = jnp.full((m,), 1.0 / m)
+    geom = FactoredPositive(xi=xi, zeta=zt, eps=0.5)
+    _, log_rmatvec = geom.log_operators()
+    plan = geometry_ops(geom, backend="interpret", mode="log")
+    step, init = plan.make_step(a, b, momentum=momentum)
+    carry = init(jnp.where(a > 0, 0.0, -jnp.inf), jnp.zeros_like(b))
+    for _ in range(5):
+        carry, _ = step(carry)
+        f, _, lcol = carry
+        assert lcol.shape == b.shape
+        np.testing.assert_allclose(np.asarray(lcol),
+                                   np.asarray(log_rmatvec(f)),
+                                   rtol=2e-6, atol=2e-6)
+
+    kw = dict(tol=1e-5, max_iter=4000, momentum=momentum)
+    res_p = sinkhorn_log_geometry(geom, a, b, use_pallas=True, **kw)
+    res_x = sinkhorn_log_geometry(geom, a, b, use_pallas=False, **kw)
+    assert int(res_p.n_iter) == int(res_x.n_iter) > 5
+    for field in ("f", "g"):
+        got, want = getattr(res_p, field), getattr(res_x, field)
+        finite = np.isfinite(np.asarray(want))
+        assert np.array_equal(finite, np.isfinite(np.asarray(got)))
+        np.testing.assert_allclose(np.asarray(got)[finite],
+                                   np.asarray(want)[finite],
+                                   rtol=2e-4, atol=2e-4, err_msg=field)
+    np.testing.assert_allclose(float(res_p.marginal_err),
+                               float(res_x.marginal_err), rtol=1e-3,
                                atol=1e-7)
 
 
