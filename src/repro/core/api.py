@@ -354,7 +354,7 @@ def _run_sharded(geom, a, b, *, tol, max_iter, momentum, f_init, g_init,
         mesh, geom, a, b, axis=mesh_axis, mode=mode, tol=tol,
         max_iter=max_iter, momentum=momentum, f_init=f_init, g_init=g_init,
         inner_steps=inner_steps, check_every=check_every,
-        precision=precision,
+        precision=precision, use_pallas=use_pallas,
     )
 
 
@@ -1374,18 +1374,15 @@ def solve_many(
                     f"solve_many(mesh=...) supports methods "
                     f"{sorted(_SHARDED_TWIN)}, got {method!r}"
                 )
-            # use_pallas is moot here: sharded geometries refuse fused local
-            # plans (they would drop the psum), so the XLA operators always
-            # run. inner_steps is NOT moot — it is passed through so the
-            # sharded runner raises its clear megakernel-refusal error
-            # instead of silently dropping the knob; check_every/precision
-            # apply as everywhere.
+            # every execution knob passes through: use_pallas picks the
+            # per-shard fused log plan, inner_steps reaches the sharded
+            # runner's megakernel refusal instead of being dropped
             return [
                 solve(p.__class__(p.geometry.rebuild_at(eps), p.a, p.b),
                       method=twin, tol=tol, max_iter=max_iter,
                       momentum=momentum, mesh=mesh, mesh_axis=mesh_axis,
-                      inner_steps=inner_steps, check_every=check_every,
-                      precision=precision)
+                      use_pallas=use_pallas, inner_steps=inner_steps,
+                      check_every=check_every, precision=precision)
                 for p in problems
             ]
         engine = get_engine(

@@ -24,6 +24,15 @@ all-to-all per iteration). The r-vector collective is the entire
 communication cost of the paper's method — the term quantified in
 EXPERIMENTS.md §Roofline.
 
+Log-domain solves run the fused per-iteration log kernels on each shard
+(``kernels.ops.geometry_ops`` with the sharded geometry's ``axis``): the
+stage-1 contraction kernel reduces the local rows, the (r, 1) partial LSEs
+are combined across the axis (one ``pmax`` and one ``psum`` of r floats),
+and the half-step kernel that reads the result is local. A trip is two
+contractions, two half-steps, two combines and the psum'd error. The
+scaling domain and families with no fused plan keep the psum'd XLA
+operators below; the persistent megakernel stays single-device.
+
 Sharding is a first-class execution mode of the Geometry layer:
 
 * :class:`RowShardedGeometry` wraps ANY feature-capable geometry's
@@ -49,13 +58,16 @@ single-device solve elementwise, not just at the fixed point.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..distributed.sharding import psum_logsumexp
+from ..kernels.backend import resolve_backend
 from .api import _pad_rows
 from .geometry import (
     ArcCosinePointCloud,
@@ -170,9 +182,15 @@ class _PsumOpsMixin:
         return self.log_operators()[1](f)
 
     def pallas_ops(self):
-        # a fused local plan has no psum in its iteration — every other
-        # device's feature rows would be silently dropped. No fused path.
-        return None
+        """The wrapped family's spec on this device's rows, plus the mesh
+        ``axis``: ``kernels.ops.geometry_ops`` then builds the per-shard
+        log plan, which combines each stage-1 partial LSE across the axis
+        (one r-vector ``pmax`` + ``psum``) before the local half-step reads
+        it, so every device's rows count. Scaling mode has no per-shard
+        plan."""
+        base = getattr(self, "base", None)
+        spec = super().pallas_ops() if base is None else base.pallas_ops()
+        return None if spec is None else dict(spec, axis=self.axis)
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -335,10 +353,11 @@ def _prepare(mesh, geom: Geometry, axis: str):
 
 def _shard_geometry_args(geom: Geometry, axis: str, p: int):
     """Pad the row-sharded fields to multiples of p and build the flat
-    (arrays, in_specs, rebuild) triple the shard_map wrapper consumes.
+    (arrays, in_specs, layout) triple the shard_map wrapper consumes.
 
-    ``rebuild(*arrays)`` reconstructs the per-device geometry inside the
-    body from the local array shards plus the (closed-over) static fields.
+    ``_rebuild(layout, arrays)`` reconstructs the per-device geometry
+    inside the body from the local array shards plus the static fields;
+    ``layout`` is hashable, so it keys the compiled sharded program.
     """
     n, m = geom.shape
     n_pad = -(-n // p) * p
@@ -354,13 +373,14 @@ def _shard_geometry_args(geom: Geometry, axis: str, p: int):
             specs.append(P())                   # replicated (anchors, ...)
         names.append(name)
         arrays.append(val)
-    cls = type(geom)
-    statics = _static_kwargs(geom)
+    layout = (type(geom), tuple(names),
+              tuple(sorted(_static_kwargs(geom).items())))
+    return arrays, tuple(specs), layout, (n, m, n_pad, m_pad)
 
-    def rebuild(*arrs) -> Geometry:
-        return cls(**dict(zip(names, arrs)), **statics)
 
-    return arrays, tuple(specs), rebuild, (n, m, n_pad, m_pad)
+def _rebuild(layout, arrays) -> Geometry:
+    cls, names, statics = layout
+    return cls(**dict(zip(names, arrays)), **dict(statics))
 
 
 def _result_specs(axis: str) -> SinkhornResult:
@@ -379,28 +399,29 @@ def _result_specs(axis: str) -> SinkhornResult:
 
 def _sharded_body(geom_local: Geometry, a, b, w1, w2, *, axis, mode,
                   tol, max_iter, momentum, check_every=1,
-                  precision="highest") -> SinkhornResult:
+                  precision="highest", use_pallas=None) -> SinkhornResult:
     """Runs INSIDE shard_map. All arrays are per-device shards.
 
     Composes the SAME solver entry points as the single-device path —
     ``sinkhorn_geometry`` / ``sinkhorn_log_geometry`` with their
     ``make_scaling_step`` / ``make_log_step`` / ``run_marginal_loop``
     building blocks unchanged. The only distribution-aware pieces are the
-    geometry's psum'd operators and the psum'd scalar reductions selected
-    through ``geom.spmd_axis`` — masking, warm starts and momentum are
-    byte-for-byte the single-device semantics.
+    geometry's psum'd operators (or, in log mode under ``use_pallas``, the
+    per-shard fused plan with its cross-device LSE combine) and the psum'd
+    scalar reductions selected through ``geom.spmd_axis`` — masking, warm
+    starts and momentum are byte-for-byte the single-device semantics.
     """
     if geom_local.spmd_axis is None:
         geom_local = RowShardedGeometry(base=geom_local, axis=axis)
     if mode == "log":
         return sinkhorn_log_geometry(
             geom_local, a, b, tol=tol, max_iter=max_iter, momentum=momentum,
-            f_init=w1, g_init=w2, use_pallas=False,
+            f_init=w1, g_init=w2, use_pallas=use_pallas,
             check_every=check_every, precision=precision,
         )
     return sinkhorn_geometry(
         geom_local, a, b, tol=tol, max_iter=max_iter, momentum=momentum,
-        u_init=w1, use_pallas=False, check_every=check_every,
+        u_init=w1, use_pallas=use_pallas, check_every=check_every,
         precision=precision,
     )
 
@@ -429,7 +450,7 @@ def sharded_sinkhorn_geometry(
     tol: float = 1e-6, max_iter: int = 2000, momentum: float = 1.0,
     f_init: Optional[jax.Array] = None, g_init: Optional[jax.Array] = None,
     inner_steps: Optional[int] = None, check_every: Optional[int] = None,
-    precision: str = "highest",
+    precision: str = "highest", use_pallas: Optional[bool] = None,
 ) -> SinkhornResult:
     """Shard-map solve of any feature-capable Geometry on ``mesh``.
 
@@ -444,7 +465,23 @@ def sharded_sinkhorn_geometry(
     (eps-annealing across sharded stages) and ``momentum`` applies the
     usual over-relaxation — semantics identical to the single-device
     solvers, whose step builders run unchanged inside the SPMD body.
+    ``use_pallas`` is the single-device policy (``None``: on where the
+    backend compiles Pallas): log mode then runs the fused kernels on each
+    shard; scaling mode has no per-shard plan and counts one
+    ``ot.shard.fallbacks``. The ``ot.shard`` span covers the host part of
+    the call, from the checks to the dispatch of the lazy result.
     """
+    with obs.span("ot.shard"):
+        return _sharded_solve(
+            mesh, geom, a, b, axis=axis, mode=mode, tol=tol,
+            max_iter=max_iter, momentum=momentum, f_init=f_init,
+            g_init=g_init, inner_steps=inner_steps, check_every=check_every,
+            precision=precision, use_pallas=use_pallas)
+
+
+def _sharded_solve(mesh, geom, a, b, *, axis, mode, tol, max_iter, momentum,
+                   f_init, g_init, inner_steps, check_every, precision,
+                   use_pallas) -> SinkhornResult:
     if mode not in ("auto", "scaling", "log"):
         raise ValueError(
             f"mode must be 'auto' | 'scaling' | 'log', got {mode!r}"
@@ -466,8 +503,14 @@ def sharded_sinkhorn_geometry(
             f"{type(geom).__name__} has no log-domain operators; use "
             "mode='scaling'"
         )
+    if use_pallas is None:
+        use_pallas = not resolve_backend().interpret
+    if use_pallas and mode == "scaling":
+        # every family _prepare admits has a per-shard log plan; the
+        # scaling domain has none and runs the psum'd XLA operators
+        obs.count("ot.shard.fallbacks")
     p = mesh.shape[axis]
-    arrays, geom_specs, rebuild, (n, m, n_pad, m_pad) = \
+    arrays, geom_specs, layout, (n, m, n_pad, m_pad) = \
         _shard_geometry_args(geom, axis, p)
     dtype = a.dtype
     eps = geom.eps
@@ -493,26 +536,39 @@ def sharded_sinkhorn_geometry(
         w1 = _pad_rows(u0, n_pad, replicate=False)
         w2 = _pad_rows(jnp.ones((m,), dtype), m_pad, replicate=False)
 
-    def body(*args):
-        geom_local = rebuild(*args[:len(arrays)])
-        la, lb, lw1, lw2 = args[len(arrays):]
-        return _sharded_body(
-            geom_local, la, lb, lw1, lw2, axis=axis, mode=mode, tol=tol,
-            max_iter=max_iter, momentum=momentum, check_every=check_every,
-            precision=precision,
-        )
-
-    fn = jax.shard_map(
-        body, mesh=mesh,
-        in_specs=geom_specs + (P(axis), P(axis), P(axis), P(axis)),
-        out_specs=_result_specs(axis),
-        check_vma=False,
-    )
-    res = fn(*arrays, a_p, b_p, w1, w2)
+    res = _sharded_program(
+        tuple(arrays), a_p, b_p, w1, w2, mesh=mesh, layout=layout,
+        specs=geom_specs, axis=axis, mode=mode, tol=tol, max_iter=max_iter,
+        momentum=momentum, check_every=check_every, precision=precision,
+        use_pallas=use_pallas)
     if n_pad == n and m_pad == m:
         return res
     return res._replace(u=res.u[:n], v=res.v[:m],
                         f=res.f[:n], g=res.g[:m])
+
+
+@functools.partial(jax.jit, static_argnames=(
+    "mesh", "layout", "specs", "axis", "mode", "tol", "max_iter", "momentum",
+    "check_every", "precision", "use_pallas"))
+def _sharded_program(arrays, a, b, w1, w2, *, mesh, layout, specs, axis,
+                     mode, tol, max_iter, momentum, check_every, precision,
+                     use_pallas) -> SinkhornResult:
+    """The shard_map'd solve as one program, compiled once per layout,
+    sizes and knobs and then reused: run eagerly, ``shard_map`` would
+    compile each of its operations anew on every call."""
+
+    def body(arrs, la, lb, lw1, lw2):
+        return _sharded_body(
+            _rebuild(layout, arrs), la, lb, lw1, lw2, axis=axis, mode=mode,
+            tol=tol, max_iter=max_iter, momentum=momentum,
+            check_every=check_every, precision=precision,
+            use_pallas=use_pallas,
+        )
+
+    return jax.shard_map(
+        body, mesh=mesh, in_specs=(specs,) + (P(axis),) * 4,
+        out_specs=_result_specs(axis), check_vma=False,
+    )(arrays, a, b, w1, w2)
 
 
 def sharded_sinkhorn_divergence(
@@ -536,13 +592,13 @@ def sharded_sinkhorn_divergence(
     a = jnp.full((n,), 1.0 / n) if a is None else a
     b = jnp.full((m,), 1.0 / m) if b is None else b
     p = mesh.shape[axis]
-    arrays, geom_specs, rebuild, (n, m, n_pad, m_pad) = \
+    arrays, geom_specs, layout, (n, m, n_pad, m_pad) = \
         _shard_geometry_args(geom, axis, p)
     a_p = _pad_rows(a, n_pad, replicate=False)
     b_p = _pad_rows(b, m_pad, replicate=False)
 
     def body(*args):
-        geom_local = rebuild(*args[:len(arrays)])
+        geom_local = _rebuild(layout, args[:len(arrays)])
         la, lb = args[len(arrays):]
         return _divergence_body(geom_local, la, lb, axis=axis, tol=tol,
                                 max_iter=max_iter)

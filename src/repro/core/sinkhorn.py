@@ -313,12 +313,11 @@ def _maybe_pallas_plan(geom: Geometry, use_pallas: Optional[bool],
     Geometries without a fused plan (dense, Nystrom, grids) always fall
     back. :func:`_plan_loop` reports the selection through the
     ``repro.obs.observe_plan_selection`` hook.
+
+    A row-sharded geometry (``spmd_axis`` set) gets the per-shard log plan,
+    which combines each stage-1 LSE across the axis before the half-step
+    that reads it; in scaling mode it has none.
     """
-    if geom.spmd_axis is not None:
-        # a fused local plan would drop the psum — sharded geometries
-        # always run the XLA operators (their pallas_ops return None too;
-        # this guard keeps a forced use_pallas=True from probing them)
-        return None
     if use_pallas is None:
         use_pallas = not resolve_backend().interpret
     if not use_pallas:
@@ -362,12 +361,15 @@ def _resolve_cadence(plan, inner_steps: Optional[int],
 
 
 def _plan_loop(plan, step_args, *, geometry, tol, max_iter, dtype,
-               inner_steps, check_every, momentum):
+               inner_steps, check_every, momentum,
+               reduce: Callable = jnp.sum):
     """Shared hot-loop driver for both fused-plan modes: resolve the
     cadence, prefer the persistent megakernel block step (``inner_steps``
     iterations per launch, carries on-chip), fall back to the streaming
     per-iteration step at the same check cadence. Reports the selection
-    (``step`` = "megakernel" | "per_iteration") to the plan hook."""
+    (``step`` = "megakernel" | "per_iteration", ``axis`` = the plan's mesh
+    axis or ``None``) to the plan hook. ``reduce`` sums the per-iteration
+    step's marginal error (:func:`geometry_reduce`)."""
     a, b = step_args
     inner, check, auto = _resolve_cadence(plan, inner_steps, check_every)
     block = None
@@ -381,6 +383,7 @@ def _plan_loop(plan, step_args, *, geometry, tol, max_iter, dtype,
         "precision": plan.precision,
         "interpret": plan.interpret,
         "step": "per_iteration" if block is None else "megakernel",
+        "axis": plan.axis,
     })
     if block is not None:
         step, init = block
@@ -392,7 +395,7 @@ def _plan_loop(plan, step_args, *, geometry, tol, max_iter, dtype,
     # no megakernel at this shape/budget: auto keeps the exact
     # per-iteration semantics; explicit knobs keep the check cadence
     # (unrolled steps) so iteration-count semantics stay identical
-    step, init = plan.make_step(a, b, momentum=momentum)
+    step, init = plan.make_step(a, b, momentum=momentum, err_reduce=reduce)
     return init, functools.partial(
         run_marginal_loop, step, tol=tol, max_iter=max_iter, dtype=dtype,
         steps_per_check=1 if auto else check,
@@ -618,6 +621,7 @@ def sinkhorn_log_geometry(
             tol=tol, max_iter=max_iter,
             momentum=momentum, f_init=f_init, g_init=g_init,
             inner_steps=inner_steps, check_every=check_every,
+            reduce=geometry_reduce(geom),
         )
     return _log_domain_solve(
         log_matvec, log_rmatvec, a, b, eps=geom.eps, tol=tol,
@@ -672,20 +676,22 @@ def _log_domain_solve(
 
 
 def _solve_log_plan(plan, a, b, *, geometry, eps, tol, max_iter, momentum,
-                    f_init, g_init, inner_steps=None,
-                    check_every=None) -> SinkhornResult:
+                    f_init, g_init, inner_steps=None, check_every=None,
+                    reduce: Callable = jnp.sum) -> SinkhornResult:
     """Log-domain solve with the while_loop body routed through the fused
     log-feature Pallas plan — semantics identical to
     :func:`_log_domain_solve` (same iterates, masking, warm starts) up to
-    the check cadence."""
+    the check cadence. ``reduce`` (:func:`geometry_reduce`) sums the
+    marginal error and the dual value: under ``shard_map`` it psums them,
+    so every device exits the loop together and returns the whole cost."""
     f0, g0, dtype = _log_init(a, b, f_init, g_init)
     init, loop = _plan_loop(
         plan, (a, b), geometry=geometry, tol=tol, max_iter=max_iter,
         dtype=dtype, inner_steps=inner_steps, check_every=check_every,
-        momentum=momentum,
+        momentum=momentum, reduce=reduce,
     )
     it, (f, g, _), err = loop(init(f0, g0))
-    return _finish_log(a, b, f, g, it, err, eps=eps, tol=tol)
+    return _finish_log(a, b, f, g, it, err, eps=eps, tol=tol, reduce=reduce)
 
 
 def sinkhorn_log_factored(

@@ -47,6 +47,9 @@ def psum_logsumexp(x: jax.Array, axis_name: str, *, axis: int = 0) -> jax.Array:
     ``-inf``-safe: all ``-inf`` slices (the log-features of zero-weight
     padded atoms) shift by 0 instead of ``-inf`` so the result is a clean
     ``-inf`` rather than ``nan`` from ``(-inf) - (-inf)``.
+
+    Partials that are already LSEs over each device's rows (the fused
+    stage-1 kernel's output) combine as a one-row slice: ``x = t[None]``.
     """
     local_max = jax.lax.stop_gradient(jnp.max(x, axis=axis))
     # pmax has no differentiation rule — and needs none: the shift cancels

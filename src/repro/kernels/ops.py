@@ -37,6 +37,7 @@ from typing import Callable, NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from ..distributed.sharding import psum_logsumexp
 from ..obs import _PLAN_OBSERVERS  # noqa: F401  (the plan hook lives there)
 from ..obs import notify_plan_selected, observe_plan_selection
 from .backend import Backend, fused_map_admissible, resolve_backend
@@ -207,6 +208,15 @@ def fused_sinkhorn_iteration(
     return u_new, v
 
 
+def _log_contract(log_w, s, be: Backend, axis: Optional[str]):
+    """Stage-1 LSE ``LSE_i(log_w + s)`` (r, B). Under a mesh axis the
+    kernel reduces this device's rows only; the partial LSEs are one row
+    each of an LSE over the devices, so ``psum_logsumexp`` over that row
+    combines them (one pmax and one psum of r floats)."""
+    t = log_feature_contract(log_w, s, backend=be)
+    return t if axis is None else psum_logsumexp(t[None], axis, axis=0)
+
+
 def fused_log_sinkhorn_iteration(
     log_xi: jax.Array,      # (n, r)
     log_zeta: jax.Array,    # (m, r)
@@ -216,6 +226,7 @@ def fused_log_sinkhorn_iteration(
     *,
     eps: float,
     backend: Optional[Backend] = None,
+    axis: Optional[str] = None,
 ):
     """One full LOG-domain Sinkhorn iteration, Pallas end to end:
 
@@ -225,11 +236,13 @@ def fused_log_sinkhorn_iteration(
         f' = eps (log a - LSE(logXi + s))                (fused log halfstep)
 
     Returns (f', g) — the small-eps twin of :func:`fused_sinkhorn_iteration`.
+    ``axis``: the mesh axis the rows are sharded over inside ``shard_map``;
+    each contraction's partial LSE is then combined across it.
     """
     be = resolve_backend(backend)
-    t = log_feature_contract(log_xi, f / eps, backend=be)
+    t = _log_contract(log_xi, f / eps, be, axis)
     g = log_halfstep(log_zeta, t, logb, scale=eps, backend=be)
-    s = log_feature_contract(log_zeta, g / eps, backend=be)
+    s = _log_contract(log_zeta, g / eps, be, axis)
     f_new = log_halfstep(log_xi, s, loga, scale=eps, backend=be)
     return f_new, g
 
@@ -380,6 +393,12 @@ class GeometryOps(NamedTuple):
     ``backend``   — the resolved :class:`Backend` record the plan was
                     built at (budgets, split-k routing, megakernel
                     admission all key off it).
+    ``axis``      — the mesh axis the factors' rows are sharded over
+                    (log mode under ``shard_map``), else ``None``. Each
+                    stage-1 contraction then reduces the local rows only,
+                    and its (r, B) partial LSE is combined across the
+                    axis before the half-step that reads it; there is no
+                    megakernel.
     ``precision`` — the execution policy the plan was built at
                     ("highest" | "bf16"): bf16 stores/streams the factors
                     at half width; all contractions and LSE accumulations
@@ -397,6 +416,7 @@ class GeometryOps(NamedTuple):
     interpret: bool = False
     precision: str = "highest"
     backend: Optional[Backend] = None
+    axis: Optional[str] = None
 
 
 def _scaling_plan(kind: str, xi, zeta, be: Backend,
@@ -461,17 +481,18 @@ def _scaling_plan(kind: str, xi, zeta, be: Backend,
 
 
 def _log_plan(kind: str, log_xi, log_zeta, eps: float, be: Backend,
-              precision: str = "highest") -> GeometryOps:
+              precision: str = "highest",
+              axis: Optional[str] = None) -> GeometryOps:
     log_xi, log_zeta = _store_features(log_xi, log_zeta, precision)
 
     def iteration(loga, logb, f):
         return fused_log_sinkhorn_iteration(
-            log_xi, log_zeta, loga, logb, f, eps=eps, backend=be
+            log_xi, log_zeta, loga, logb, f, eps=eps, backend=be, axis=axis
         )
 
     def contract_f(f):
         """Stage-1 LSE over logXi, ``t = LSE_i(logXi + f/eps)`` (r, 1)."""
-        return log_feature_contract(log_xi, f[:, None] / eps, backend=be)
+        return _log_contract(log_xi, f[:, None] / eps, be, axis)
 
     def make_step(a, b, *, momentum: float = 1.0,
                   err_reduce: Callable = jnp.sum):
@@ -491,8 +512,7 @@ def _log_plan(kind: str, log_xi, log_zeta, eps: float, be: Backend,
             f, g, lcol = carry                # lcol = log(K^T e^{f/eps})
             # the half-step kernel's epilogue, scale * (lmarg - lse)
             g_new = relax_log(eps * (logb - lcol), g, momentum)
-            t2 = log_feature_contract(log_zeta, g_new[:, None] / eps,
-                                      backend=be)
+            t2 = _log_contract(log_zeta, g_new[:, None] / eps, be, axis)
             f_new = relax_log(
                 log_halfstep(log_xi, t2, loga, scale=eps,
                              backend=be)[:, 0], f, momentum)
@@ -507,8 +527,9 @@ def _log_plan(kind: str, log_xi, log_zeta, eps: float, be: Backend,
 
     def make_block_step(a, b, *, inner_steps: int, momentum: float = 1.0):
         n, m = a.shape[0], b.shape[0]
-        if not block_plan_fits(n, m, log_xi.shape[1], 1, log_xi.dtype,
-                               backend=be):
+        # the block iterates on this device's rows with no combine between
+        if axis is not None or not block_plan_fits(
+                n, m, log_xi.shape[1], 1, log_xi.dtype, backend=be):
             return None
         loga = _masked_log(a)[:, None]
         logb = _masked_log(b)[:, None]
@@ -533,7 +554,7 @@ def _log_plan(kind: str, log_xi, log_zeta, eps: float, be: Backend,
                        iteration=iteration, make_step=make_step, eps=eps,
                        make_block_step=make_block_step,
                        interpret=be.interpret, precision=precision,
-                       backend=be)
+                       backend=be, axis=axis)
 
 
 def _paged_scaling_plan(kind: str, xi, zeta, live_x, live_y,
@@ -618,12 +639,20 @@ def geometry_ops(geom, *,
     ambient policy applies. The whole plan — kernel routing (split-k on
     parallel-grid backends), fused-map admissibility, megakernel budget —
     keys off the one record.
+
+    A spec with an ``axis`` (a row-sharded geometry inside ``shard_map``)
+    gets the per-shard log plan, which combines each stage-1 LSE across
+    the axis; there is no per-shard scaling plan, so scaling mode returns
+    ``None`` there.
     """
     if mode not in ("scaling", "log"):
         raise ValueError(f"unknown plan mode {mode!r}")
     check_precision(precision)
     spec = geom.pallas_ops()
     if spec is None:
+        return None
+    axis = spec.get("axis")
+    if axis is not None and mode == "scaling":
         return None
     be = resolve_backend(backend)
     kind = spec["kind"]
@@ -632,12 +661,12 @@ def geometry_ops(geom, *,
         if mode == "scaling":
             return _scaling_plan(kind, xi, zeta, be, precision)
         return _log_plan(kind, _masked_log(xi), _masked_log(zeta),
-                         float(geom.eps), be, precision)
+                         float(geom.eps), be, precision, axis)
     if kind == "log_factored":
         lxi, lzt = spec["log_xi"], spec["log_zeta"]
         if mode == "log":
             return _log_plan(kind, lxi, lzt, float(spec["eps"]), be,
-                             precision)
+                             precision, axis)
         return _scaling_plan(kind, jnp.exp(lxi), jnp.exp(lzt), be,
                              precision)
     if kind == "paged":
@@ -654,7 +683,7 @@ def geometry_ops(geom, *,
             if lxi is None:
                 lxi, lzt = _masked_log(xi), _masked_log(zeta)
             return _log_plan(kind, lxi, lzt, float(spec["eps"]), be,
-                             precision)
+                             precision, axis)
         if not paged_supported(be):
             # parallel-grid backends (Triton) cannot lower the paged
             # contract's sequential accumulation — refuse into the flat
@@ -673,5 +702,6 @@ def geometry_ops(geom, *,
         xi, zeta = fmap(spec["x"]), fmap(spec["y"])
         if mode == "scaling":
             return _scaling_plan(kind, xi, zeta, be, precision)
-        return _log_plan(kind, xi, zeta, float(geom.eps), be, precision)
+        return _log_plan(kind, xi, zeta, float(geom.eps), be, precision,
+                         axis)
     raise ValueError(f"unknown pallas_ops spec kind {kind!r}")

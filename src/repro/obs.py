@@ -15,8 +15,9 @@ process saw, without reading the trace.
 
 The solvers name these spans and counters (see README, "Tracing"):
 ``ot.solve`` (with ``call``), ``ot.solve_many``, ``ot.stage``,
-``ot.featurize``, ``ot.loop``, ``ot.finish``; ``ot.solve.calls`` and
-``ot.loop.traces`` (one per trace of a solver loop).
+``ot.featurize``, ``ot.loop``, ``ot.finish``, ``ot.shard``;
+``ot.solve.calls``, ``ot.shard.fallbacks`` and ``ot.loop.traces`` (one
+per trace of a solver loop).
 
 ``observe_plan_selection`` collects the fused-plan selections the solvers
 report through ``notify_plan_selected`` (at trace time).
@@ -120,9 +121,10 @@ def notify_plan_selected(event: dict) -> None:
 def observe_plan_selection():
     """Collect plan-selection events: ``with observe_plan_selection() as ev:
     solve(...)`` then assert on ``ev`` (list of dicts with ``geometry`` /
-    ``mode`` / ``kind`` / ``precision`` / ``interpret`` keys, and ``step``:
+    ``mode`` / ``kind`` / ``precision`` / ``interpret`` keys, ``step``:
     "megakernel" for the persistent block step, "per_iteration" for the
-    streaming plan)."""
+    streaming plan, and ``axis``: the mesh axis of a per-shard plan,
+    ``None`` on one device)."""
     events: List[dict] = []
     _PLAN_OBSERVERS.append(events.append)
     try:

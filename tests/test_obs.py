@@ -155,3 +155,31 @@ def test_span_costs_little_without_a_profiler():
         with obs.span("ot.test", call=i):
             pass
     assert (time.perf_counter() - t) / n < 50e-6
+
+
+@pytest.mark.parametrize("method, fallbacks", [("sharded_log", 0),
+                                               ("sharded", 1)])
+def test_sharded_solve_records_its_span_and_fallbacks(tmp_path, method,
+                                                      fallbacks):
+    """``ot.shard`` covers the host part of a sharded solve, inside
+    ``ot.solve``; a log solve asked for the fused kernels runs the
+    per-shard plan, a scaling one has none and counts a fallback."""
+    from jax.sharding import Mesh
+    from repro.core.objective import ExecutionPolicy
+
+    mesh = Mesh(np.array(jax.devices()[:1]), ("data",))
+    spec = _spec().replace(method=method, policy=ExecutionPolicy(
+        mesh=mesh, use_pallas=True))
+    before = obs.traced()
+    with obs.observe_plan_selection() as events:
+        _, spans = _profiled(tmp_path, lambda: solve(spec))
+    after = obs.traced()
+    top, = [s for s in spans if s[0] == "ot.solve"]
+    shard, = [s for s in spans if s[0] == "ot.shard"]
+    assert _inside(shard, top)
+    s0, c0 = before["spans"].get("ot.shard", (0.0, 0))
+    s1, c1 = after["spans"].get("ot.shard", (0.0, 0))
+    assert c1 - c0 == 1 and s1 > s0
+    assert after["counters"].get("ot.shard.fallbacks", 0) - \
+        before["counters"].get("ot.shard.fallbacks", 0) == fallbacks
+    assert [e["axis"] for e in events] == ["data"] * (1 - fallbacks)

@@ -13,6 +13,7 @@ read back without one). The tests skip where no v5e can be described.
 """
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
 from jax.sharding import SingleDeviceSharding
 
@@ -176,3 +177,34 @@ def test_paged_kernel_compiles(one_chip, kernel, dtype):
                            ((N, 1), jnp.float32), ((R, 1), jnp.float32),
                            ((N // PAGE,), jnp.int32))
     _compile(_PAGED[kernel], w, v, t, live)
+
+
+# -- the sharded log solve, fused kernels per shard -------------------------
+
+
+def test_sharded_log_solve_compiles_on_four_chips(one_chip, topo):
+    """The whole ``sharded_log`` solve of a Gaussian point cloud over the
+    described 2x2 mesh, N rows a chip: the per-shard plan's kernels and
+    the cross-chip LSE combines (all-reduces) are in the one program."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.core import GaussianPointCloud, sharded_sinkhorn_geometry
+    from repro.kernels.backend import backend_scope
+
+    mesh = Mesh(np.array(topo.devices).reshape(4), ("data",))
+    rows = NamedSharding(mesh, P("data", None))
+    n = 4 * N
+    x = jax.ShapeDtypeStruct((n, 28), jnp.float32, sharding=rows)
+    anchors = jax.ShapeDtypeStruct((R, 28), jnp.float32,
+                                   sharding=NamedSharding(mesh, P()))
+
+    def run(x_, y_, u_):
+        geom = GaussianPointCloud.build(x_, y_, u_, eps=0.1, R=12.0)
+        w = jnp.full((n,), 1.0 / n)
+        return sharded_sinkhorn_geometry(mesh, geom, w, w, mode="log",
+                                         tol=1e-4, max_iter=2000).cost
+
+    with backend_scope(TPU):
+        text = _compile(run, x, x, anchors)
+    assert "_log_contract_impl" in text and "_log_halfstep_impl" in text
+    assert "all-reduce" in text
